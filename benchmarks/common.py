@@ -24,51 +24,40 @@ os.makedirs(RESULTS_DIR, exist_ok=True)
 _CACHE = os.path.join(RESULTS_DIR, "synpa_models.pkl")
 _CACHE_FAST = os.path.join(RESULTS_DIR, "synpa_models_fast.pkl")
 
-#: Default home of the JAX persistent compilation cache (opt out with
-#: ``REPRO_NO_COMPILE_CACHE=1``; relocate with ``REPRO_COMPILE_CACHE_DIR``).
-COMPILE_CACHE_DIR = os.path.join(
-    os.path.dirname(__file__), os.pardir, ".jax_cache"
+#: Home of the JAX persistent compilation cache when
+#: ``JAX_COMPILATION_CACHE_DIR`` is not set.  A fixed path: the directory
+#: is part of what a later process must find again.
+COMPILE_CACHE_DIR = os.path.abspath(
+    os.path.join(os.path.dirname(__file__), os.pardir, ".jax_cache")
 )
 
-_compile_cache_enabled: Optional[bool] = None
 
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
 
-def enable_compile_cache() -> bool:
-    """Point JAX at an on-disk compilation cache so repeated bench/smoke
-    invocations stop paying the multi-second ``jit`` warm-up for races
-    they already compiled in an earlier *process*.
-
-    Idempotent; returns whether the cache is active.  Opt out with
-    ``REPRO_NO_COMPILE_CACHE=1`` (e.g. to measure true cold-compile
-    cost — the compile-vs-steady split the recorded A/Bs report is
-    measured within one process and is unaffected either way).  The
-    cache key includes the XLA backend and version, so upgrades
-    invalidate naturally rather than deserialising stale executables.
+    Repeated bench/smoke invocations then stop paying the multi-second
+    ``jit`` warm-up for races an earlier *process* already compiled.
+    ``JAX_COMPILATION_CACHE_DIR`` places the cache (JAX reads it itself,
+    and no other directory is set here); without it the cache is the
+    checkout's ``.jax_cache/``.  JAX's own
+    ``JAX_ENABLE_COMPILATION_CACHE=false`` turns it off, e.g. to measure
+    cold compiles.  A cache that cannot be set up raises.  The cache key
+    includes the XLA backend and version, so upgrades invalidate
+    naturally rather than deserialising stale executables.
     """
-    global _compile_cache_enabled
-    if _compile_cache_enabled is not None:
-        return _compile_cache_enabled
-    if os.environ.get("REPRO_NO_COMPILE_CACHE"):
-        _compile_cache_enabled = False
-        return False
     import jax
 
-    cache_dir = os.environ.get("REPRO_COMPILE_CACHE_DIR") or os.path.abspath(
-        COMPILE_CACHE_DIR
-    )
-    try:
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = COMPILE_CACHE_DIR
         os.makedirs(cache_dir, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # Every race here is worth caching: the open-system scan compiles
-        # for tens of seconds at N=256, and the smoke tier's small races
-        # still dominate its wall time.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        _compile_cache_enabled = True
-    except Exception as e:  # pragma: no cover - jax without the knobs
-        print(f"# persistent compilation cache unavailable: {e}")
-        _compile_cache_enabled = False
-    return _compile_cache_enabled
+    # Every race here is worth caching: the open-system scan compiles for
+    # tens of seconds at N=256, and the smoke tier's small races still
+    # dominate its wall time.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return cache_dir
 
 
 def _load_cache(path: str):

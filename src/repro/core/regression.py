@@ -108,15 +108,18 @@ def fit(
     st_j = jnp.asarray(st_j, jnp.float32)
     smt_i = jnp.asarray(smt_i, jnp.float32)
 
+    # Full f32 contractions: at a TPU's default precision the normal
+    # equations move the coefficients by ~0.1.
+    hi = jax.lax.Precision.HIGHEST
     coeffs, mses = [], []
     eye = jnp.eye(4, dtype=jnp.float32)
     for c in range(n_categories):
         X = design_matrix(st_i[:, c], st_j[:, c])
         y = smt_i[:, c]
-        gram = X.T @ X + ridge * eye
-        w = jnp.linalg.solve(gram, X.T @ y)
+        gram = jnp.matmul(X.T, X, precision=hi) + ridge * eye
+        w = jnp.linalg.solve(gram, jnp.matmul(X.T, y, precision=hi))
         coeffs.append(w)
-        mses.append(jnp.mean((X @ w - y) ** 2))
+        mses.append(jnp.mean((jnp.matmul(X, w, precision=hi) - y) ** 2))
     while len(coeffs) < isc.N_CATS:
         coeffs.append(jnp.zeros(4, jnp.float32))
         mses.append(jnp.zeros((), jnp.float32))
@@ -356,8 +359,11 @@ def _make_lm_step(model: CategoryModel, frac_i, frac_j):
         # model once (at the trial point), not twice.
         x, y = to_simplex(z_i), to_simplex(z_j)
         J = jac(x, y)
-        grad = jnp.einsum("...ki,...k->...i", J, rv)
-        H = jnp.einsum("...ki,...kj->...ij", J, J)
+        # Full f32 normal equations (a TPU's default precision would
+        # round J to bf16).
+        hi = jax.lax.Precision.HIGHEST
+        grad = jnp.einsum("...ki,...k->...i", J, rv, precision=hi)
+        H = jnp.einsum("...ki,...kj->...ij", J, J, precision=hi)
         diag = jnp.diagonal(H, axis1=-2, axis2=-1)
         A = H + (lam[..., None, None] * diag[..., None, :] + 1e-8) * eye2
         delta = _chol_solve_small(A, -grad, two_c)
@@ -609,32 +615,6 @@ def _hb_best_of(model: CategoryModel, frac_i, frac_j, n_steps: int,
     z_i = jnp.where(better_b, zb[0], za[0])
     z_j = jnp.where(better_b, zb[1], za[1])
     return to_simplex(z_i), to_simplex(z_j)
-
-
-def _register_barrier_batching() -> None:
-    """Give ``lax.optimization_barrier`` a ``vmap`` rule when the
-    installed jax lacks one (0.4.x): identity per operand, batch dims
-    pass through untouched.  The barrier pins the *compiler* (no hoist,
-    no CSE); batching it per-lane changes nothing about that contract.
-    Registered here because :func:`_gn_with_fallback` barriers its
-    fallback inputs and must stay ``vmap``-able without importing the
-    higher layers (``repro.smt.scan_engine`` keeps its own guarded
-    call for import-order independence)."""
-    try:
-        from jax._src.lax import lax as _lax_impl
-        from jax.interpreters import batching as _batching
-
-        prim = _lax_impl.optimization_barrier_p
-        if prim not in _batching.primitive_batchers:
-            def _identity_batcher(args, dims, **params):
-                return prim.bind(*args, **params), list(dims)
-
-            _batching.primitive_batchers[prim] = _identity_batcher
-    except Exception:  # pragma: no cover - newer jax ships its own rule
-        pass
-
-
-_register_barrier_batching()
 
 
 def _run_at_most_once(pred, fn, init):

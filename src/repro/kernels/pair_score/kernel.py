@@ -2,12 +2,15 @@
 
 At cluster scale the SYNPA policy re-scores every pair of N runnable jobs
 each quantum: O(N^2 * C) fused multiply-adds plus clipping.  The kernel
-tiles the (N, N) pair grid into (BM, BN) VMEM blocks; the two stack slices
-(BM, C) and (BN, C) and the tiny (C, 4) coefficient table live in VMEM, and
-the C-category reduction is unrolled (C = 4).  VPU-only (no MXU) — the op is
-elementwise-dominated, so the roofline here is HBM bandwidth on the (N, N)
-output: one pass, fully fused, versus 5+ materialised intermediates for the
-naive XLA lowering.
+tiles the (N, N) pair grid into (BM, BN) VMEM blocks.  The i-side stacks
+arrive as a (BM, C) block and the j-side stacks as a (C, BN) block of the
+transposed (C, N) stack matrix, so each category is a column of one and a
+row of the other: both broadcast across the tile without a relayout.  The
+tiny (C, 4) coefficient table lives in SMEM and is read as scalars, and
+the C-category reduction is unrolled (C = 4).  VPU-only (no MXU) — the op
+is elementwise-dominated, so the roofline here is HBM bandwidth on the
+(N, N) output: one pass, fully fused, versus 5+ materialised
+intermediates for the naive XLA lowering.
 """
 
 from __future__ import annotations
@@ -24,27 +27,26 @@ from repro.kernels.pair_score.ref import DIAG, MAX_SLOWDOWN, MIN_SLOWDOWN
 BLOCK = 128
 
 
-def _pair_score_kernel(st_i_ref, st_j_ref, coeffs_ref, out_ref, *,
+def _pair_score_kernel(coeffs_ref, st_i_ref, st_jt_ref, out_ref, *,
                        n_categories: int, n_total: int, block: int):
     """One (BM, BN) tile of the pair-cost matrix."""
     bi = pl.program_id(0)
     bj = pl.program_id(1)
     st_i = st_i_ref[...]          # (BM, C) f32
-    st_j = st_j_ref[...]          # (BN, C) f32
-    coeffs = coeffs_ref[...]      # (C, 4) f32
+    st_jt = st_jt_ref[...]        # (C, BN) f32
 
-    bm, c = st_i.shape
-    bn = st_j.shape[0]
+    bm = st_i.shape[0]
+    bn = st_jt.shape[1]
     s_ij = jnp.zeros((bm, bn), jnp.float32)
     s_ji = jnp.zeros((bm, bn), jnp.float32)
     # Unrolled category loop: each term is rank-1 in the tile -> stays VPU.
     for cat in range(n_categories):
-        a = coeffs[cat, 0]
-        b = coeffs[cat, 1]
-        g = coeffs[cat, 2]
-        r = coeffs[cat, 3]
-        xi = st_i[:, cat][:, None]            # (BM, 1)
-        xj = st_j[:, cat][None, :]            # (1, BN)
+        a = coeffs_ref[cat, 0]
+        b = coeffs_ref[cat, 1]
+        g = coeffs_ref[cat, 2]
+        r = coeffs_ref[cat, 3]
+        xi = st_i[:, cat:cat + 1]             # (BM, 1)
+        xj = st_jt[cat:cat + 1, :]            # (1, BN)
         cross = xi * xj
         s_ij += jnp.maximum(a + b * xi + g * xj + r * cross, 0.0)
         s_ji += jnp.maximum(a + b * xj + g * xi + r * cross, 0.0)
@@ -71,29 +73,24 @@ def pair_score_pallas(st, coeffs, n_categories: int = 4,
     n, c = st.shape
     assert n % block == 0, "ops.py pads N to the block size"
     n_valid = n if n_valid is None else n_valid
-    grid = (n // block, n // block)
+    st = st.astype(jnp.float32)
     kernel = functools.partial(
         _pair_score_kernel, n_categories=n_categories, n_total=n_valid,
         block=block)
     # Every (i, j) tile is independent: mark both grid dims parallel so
-    # Mosaic is free to reorder/overlap tiles, and bound VMEM to the two
-    # stack slices + coefficient table + output tile (with double-buffering
-    # headroom) so huge grids can't over-allocate.
-    vmem_bytes = 4 * (2 * block * c + c * 4 + block * block) * 4
+    # Mosaic is free to reorder/overlap tiles.
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(n // block, n // block),
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((block, c), lambda i, j: (i, 0)),
-            pl.BlockSpec((block, c), lambda i, j: (j, 0)),
-            pl.BlockSpec((c, 4), lambda i, j: (0, 0)),
+            pl.BlockSpec((c, block), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((block, block), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, n), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
-            vmem_limit_bytes=max(vmem_bytes, 1 << 20),
         ),
         interpret=interpret,
-    )(st.astype(jnp.float32), st.astype(jnp.float32),
-      coeffs.astype(jnp.float32))
+    )(coeffs.astype(jnp.float32), st, st.T)

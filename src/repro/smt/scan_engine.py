@@ -112,32 +112,6 @@ from repro.smt.machine import (
 SCAN_RNG_STREAM_VERSION = 2
 
 
-def _register_barrier_batching() -> None:
-    """Give ``lax.optimization_barrier`` a ``vmap`` rule when the
-    installed jax lacks one (0.4.x): identity per operand, batch dims
-    pass through untouched.  The barrier exists to pin the *compiler*
-    (no CSE between the telemetry shadow recompute and the quantum's own
-    arithmetic — see ``_scan_telemetry``); batching it per-lane changes
-    nothing about that contract, and without the rule the batched-
-    scenario dispatches of ``repro.online.batch_sim`` cannot carry
-    telemetry rings."""
-    try:
-        from jax._src.lax import lax as _lax_impl
-        from jax.interpreters import batching as _batching
-
-        prim = _lax_impl.optimization_barrier_p
-        if prim not in _batching.primitive_batchers:
-            def _identity_batcher(args, dims, **params):
-                return prim.bind(*args, **params), list(dims)
-
-            _batching.primitive_batchers[prim] = _identity_batcher
-    except Exception:  # pragma: no cover - newer jax ships its own rule
-        pass
-
-
-_register_barrier_batching()
-
-
 @dataclasses.dataclass(frozen=True)
 class DeviceTables:
     """jnp (float32) mirror of :class:`repro.smt.machine.PhaseTables`."""

@@ -9,6 +9,7 @@ fused warm-streaming path over the recorded baseline fails the suite.
 
 import os
 import subprocess
+import sys
 
 import pytest
 
@@ -30,3 +31,31 @@ def test_bench_smoke_script_runs():
     assert "online_churn," in out, out
     assert "cluster_scale," in out, out
     assert "policy_guard:" in out and "REGRESSION" not in out, out
+
+
+_CACHE_PROBE = """
+import jax
+from benchmarks.common import enable_compile_cache
+d = enable_compile_cache()
+jax.jit(lambda x: x * 3 + 1)(2.0).block_until_ready()
+print(d)
+print(jax.config.jax_compilation_cache_dir)
+"""
+
+
+@pytest.mark.parametrize("placed", [True, False])
+def test_compile_cache_placement(tmp_path, placed):
+    """``JAX_COMPILATION_CACHE_DIR`` places the cache and nothing in code
+    overrides it; without it the cache is the checkout's ``.jax_cache``."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(_ROOT, "src")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = os.path.join(_ROOT, ".jax_cache")
+    if placed:
+        want = str(tmp_path / "cache")
+        env["JAX_COMPILATION_CACHE_DIR"] = want
+    res = subprocess.run([sys.executable, "-c", _CACHE_PROBE], cwd=_ROOT,
+                         env=env, capture_output=True, text=True, timeout=120)
+    assert res.returncode == 0, res.stderr
+    assert res.stdout.split() == [want, want]
+    assert os.listdir(want), "nothing was written to the cache"
