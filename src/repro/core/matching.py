@@ -52,6 +52,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.obs import trace as obs_trace
+
 Pairs = List[Tuple[int, int]]
 
 _INT_SCALE = 10**6
@@ -858,6 +860,8 @@ def matching_cost(cost: np.ndarray, pairs: Pairs) -> float:
 # the greedy seed masks them to +inf and the 2-opt freezes their pairs.
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("matcher")
+@jax.named_scope("seed")
 def device_seed_partner(cost, valid):
     """Complementary sort seed of the device tier, in-graph and loop-free.
 
@@ -919,6 +923,8 @@ def _partner_to_pair_arrays(partner, valid):
     return i_arr, j_arr, valid[i_arr]
 
 
+@jax.named_scope("matcher")
+@jax.named_scope("two_opt")
 def device_two_opt_partner(cost, partner, valid, eps=1e-9,
                            max_rounds: Optional[int] = None,
                            with_rounds: bool = False):
@@ -1018,38 +1024,12 @@ def device_pairs_partner(cost, valid, eps=1e-9,
                                   with_rounds=with_rounds)
 
 
-def device_repair_partner(cost, partner, valid, eps=1e-9,
-                          max_rounds: Optional[int] = None,
-                          with_diag: bool = False):
-    """Masked churn repair of a carried partner vector, in-graph.
-
-    The device twin of :func:`repair_pairs` for *partial occupancy*: the
-    validity mask of the open system changes every quantum (arrivals fill
-    slots, departures empty them, the idle vertex toggles with the active
-    population's parity), so the carried matching must be repaired — not
-    rebuilt — under a mask whose contents shift while its shape stays put.
-
-    ``partner`` is the previous quantum's (P,) involution; ``valid`` marks
-    the vertices to be matched now (active slots + the idle vertex when the
-    population is odd; popcount must be even).  Pairs whose two endpoints
-    are both still valid are *kept*; the uncovered valid vertices — the
-    dirty set: arrivals, widows, a toggled idle vertex — are ranked by
-    interference degree (mean pairable cost among themselves, the
-    :func:`device_seed_partner` metric) and paired complementarily,
-    heaviest with lightest.  Invalid vertices pair among themselves by
-    index.  A bounded masked 2-opt (:func:`device_two_opt_partner`) then
-    ripples the repair outward through the kept pairs.
-
-    Everything is a pure function of (cost, partner, valid): no host
-    branches, so the churn repair can ride inside a ``lax.scan`` body with
-    churn-stable shapes.  Same local-optimality class as the host repair
-    tier, never bit-identical to it (acceptance order differs).
-
-    ``with_diag=True`` (static) returns ``(partner, rounds, n_dirty)``:
-    the 2-opt round counter plus the int32 dirty-vertex count the repair
-    re-paired this call — the telemetry ring's churn-repair counters.
-    The partner vector is bit-identical either way.
-    """
+@jax.named_scope("matcher")
+@jax.named_scope("repair")
+def _repair_seed(cost, partner, valid):
+    """The churn repair's seed: kept pairs stay, the dirty vertices pair
+    complementarily by interference degree, invalid ones by index.
+    Returns the seeded partner vector and the dirty-vertex count."""
     p = partner.shape[0]
     idx = jnp.arange(p, dtype=jnp.int32)
     pt = partner.astype(jnp.int32)
@@ -1082,6 +1062,42 @@ def device_repair_partner(cost, partner, valid, eps=1e-9,
     # lanes under vmap.
     repaired = order[mate_pos][jnp.argsort(order)]
     repaired = jnp.where(keep, pt, repaired)
+    return repaired, nd
+
+
+def device_repair_partner(cost, partner, valid, eps=1e-9,
+                          max_rounds: Optional[int] = None,
+                          with_diag: bool = False):
+    """Masked churn repair of a carried partner vector, in-graph.
+
+    The device twin of :func:`repair_pairs` for *partial occupancy*: the
+    validity mask of the open system changes every quantum (arrivals fill
+    slots, departures empty them, the idle vertex toggles with the active
+    population's parity), so the carried matching must be repaired — not
+    rebuilt — under a mask whose contents shift while its shape stays put.
+
+    ``partner`` is the previous quantum's (P,) involution; ``valid`` marks
+    the vertices to be matched now (active slots + the idle vertex when the
+    population is odd; popcount must be even).  Pairs whose two endpoints
+    are both still valid are *kept*; the uncovered valid vertices — the
+    dirty set: arrivals, widows, a toggled idle vertex — are ranked by
+    interference degree (mean pairable cost among themselves, the
+    :func:`device_seed_partner` metric) and paired complementarily,
+    heaviest with lightest.  Invalid vertices pair among themselves by
+    index.  A bounded masked 2-opt (:func:`device_two_opt_partner`) then
+    ripples the repair outward through the kept pairs.
+
+    Everything is a pure function of (cost, partner, valid): no host
+    branches, so the churn repair can ride inside a ``lax.scan`` body with
+    churn-stable shapes.  Same local-optimality class as the host repair
+    tier, never bit-identical to it (acceptance order differs).
+
+    ``with_diag=True`` (static) returns ``(partner, rounds, n_dirty)``:
+    the 2-opt round counter plus the int32 dirty-vertex count the repair
+    re-paired this call — the telemetry ring's churn-repair counters.
+    The partner vector is bit-identical either way.
+    """
+    repaired, nd = _repair_seed(cost, partner, valid)
     if with_diag:
         out, rounds = device_two_opt_partner(
             cost, repaired, valid, eps=eps, max_rounds=max_rounds,
@@ -1113,10 +1129,12 @@ def device_pairs(cost, valid=None, eps: float = 1e-9,
     else:
         valid_np = np.asarray(valid, bool)
     assert int(valid_np.sum()) % 2 == 0, "valid vertex count must be even"
-    partner = np.asarray(
-        _device_pairs_jit(cost, jnp.asarray(valid_np), eps,
-                          max_rounds)
-    )
+    partner_dev = _device_pairs_jit(cost, jnp.asarray(valid_np), eps,
+                                    max_rounds)
+    # The caller blocks here on the matcher (and whatever it queued
+    # behind) and the device-to-host copy.
+    with obs_trace.span("matcher.wait"):
+        partner = np.asarray(partner_dev)
     return sorted(
         (int(v), int(partner[v]))
         for v in range(p)

@@ -524,6 +524,7 @@ def inverse_gn_trace(
     return st_i, st_j, trace
 
 
+@jax.named_scope("inverse")
 def inverse(
     model: CategoryModel,
     frac_i,
@@ -775,6 +776,7 @@ def inverse_trace(
     return to_simplex(z_i), to_simplex(z_j), trace
 
 
+@jax.named_scope("pair_cost")
 def pair_cost_matrix(model: CategoryModel, st_stacks, impl: str = "xla",
                      n_valid=None):
     """Dense all-pairs cost: cost[i, j] = slowdown(i|j) + slowdown(j|i).

@@ -161,6 +161,7 @@ def make_fused_step(
     uniform = jnp.asarray(isc.uniform_stack(method.n_categories))
 
     @jax.jit
+    @jax.named_scope("synpa_step")
     def step(counters, partner, prev_st, masks, idle):
         solve_mask, solo_mask, valid_mask, fresh_mask = (
             masks[0], masks[1], masks[2], masks[3]
@@ -170,11 +171,12 @@ def make_fused_step(
         idx = jnp.arange(n)
 
         # Step 0: measured SMT stack fractions of every slot.
-        raw = isc.raw_stack(
-            counters[:, 0], counters[:, 1], counters[:, 2], counters[:, 3],
-            dtype=jnp.float32,
-        )
-        frac = isc.build_stack(raw, method)
+        with jax.named_scope("isc"):
+            raw = isc.raw_stack(
+                counters[:, 0], counters[:, 1], counters[:, 2],
+                counters[:, 3], dtype=jnp.float32,
+            )
+            frac = isc.build_stack(raw, method)
 
         # Step 1: one inverse solve per co-running *pair*.  Row i and row j
         # pose the same system with the roles swapped, so only the
@@ -190,35 +192,36 @@ def make_fused_step(
         fi = jnp.where(v1, frac[take], uniform)
         fj = jnp.where(v1, frac[p_take], uniform)
         idiag = None
-        if solver == "gn":
-            if with_diag:
-                si, sj, idiag = regression._gn_with_fallback(
-                    model, fi, fj, gn_steps=gn_steps, hb_steps=hb_steps,
-                    lr=lr, return_diag=True,
-                )
+        with jax.named_scope("inverse"):
+            if solver == "gn":
+                if with_diag:
+                    si, sj, idiag = regression._gn_with_fallback(
+                        model, fi, fj, gn_steps=gn_steps, hb_steps=hb_steps,
+                        lr=lr, return_diag=True,
+                    )
+                else:
+                    si, sj = regression._gn_with_fallback(
+                        model, fi, fj, gn_steps=gn_steps, hb_steps=hb_steps,
+                        lr=lr
+                    )
             else:
-                si, sj = regression._gn_with_fallback(
-                    model, fi, fj, gn_steps=gn_steps, hb_steps=hb_steps,
-                    lr=lr
+                assert solver == "hb", solver
+                if warm:
+                    ii = jnp.where(v1, prev_st[take], uniform)
+                    ij = jnp.where(v1, prev_st[p_take], uniform)
+                else:
+                    ii = ij = None
+                si, sj = regression._hb_best_of(
+                    model, fi, fj, hb_steps, lr, init_i=ii, init_j=ij
                 )
-        else:
-            assert solver == "hb", solver
-            if warm:
-                ii = jnp.where(v1, prev_st[take], uniform)
-                ij = jnp.where(v1, prev_st[p_take], uniform)
-            else:
-                ii = ij = None
-            si, sj = regression._hb_best_of(
-                model, fi, fj, hb_steps, lr, init_i=ii, init_j=ij
-            )
-            if with_diag:
-                idiag = regression.InverseDiag(
-                    iters=jnp.full(valid.shape, hb_steps, jnp.int32),
-                    residual=regression.inverse_residual(
-                        model, fi, fj, si, sj
-                    ),
-                    fallback=jnp.zeros(valid.shape, bool),
-                )
+                if with_diag:
+                    idiag = regression.InverseDiag(
+                        iters=jnp.full(valid.shape, hb_steps, jnp.int32),
+                        residual=regression.inverse_residual(
+                            model, fi, fj, si, sj
+                        ),
+                        fallback=jnp.zeros(valid.shape, bool),
+                    )
         # Deliver the pair solves by gather, not scatter (a scatter with
         # computed indices lowers to a serial per-element loop on
         # XLA:CPU and serializes across lanes under vmap): slot s is the

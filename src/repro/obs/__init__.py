@@ -11,8 +11,9 @@ away.  This package closes the loop with three layers:
   the one-dispatch transfer-guard contract is preserved and telemetry-off
   runs stay bit-identical to the uninstrumented engines.
 * :mod:`repro.obs.trace` — host span tracing: nestable context-manager
-  spans emitting Chrome/Perfetto trace-event JSON, wrapping
-  ``jax.profiler.TraceAnnotation`` when profiling is active.
+  spans, always on the profiler's clock (``jax.profiler.TraceAnnotation``)
+  and in a bounded in-memory record, with Chrome/Perfetto trace-event
+  JSON on request.
 * :mod:`repro.obs.metrics` — the version-stamped run-report layer: one
   export format (``export_run``/``save_run``/``load_run``) unifying the
   ad-hoc benchmark JSON fields, rendered and diffed by

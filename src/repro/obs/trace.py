@@ -1,70 +1,70 @@
-"""Host span tracing — Chrome/Perfetto trace events for the run pipeline.
+"""Host span tracing — program phases on the profiler's clock and in memory.
 
 The device engines are one dispatch per run, so the host-side story of a
-run is a handful of coarse phases: presample -> commit -> compile ->
-dispatch -> fetch -> stats (and, on the host engines, the per-quantum
-event-loop phases).  :func:`span` wraps each phase as a context manager;
-when tracing is enabled the spans are recorded as Chrome trace-event
-``"X"`` (complete) events — microsecond timestamps, pid/tid — which
-``save`` writes as a JSON file loadable in ``chrome://tracing`` or
-https://ui.perfetto.dev.  When ``jax.profiler`` is importable each span
-also wraps a ``TraceAnnotation``, so the spans line up with XLA's own
-rows inside a ``jax.profiler.trace`` capture.
+run is a handful of coarse phases: presample -> pack -> commit -> dispatch
+-> fetch -> stats, and, on the served path, each allocator decision
+(``alloc.pair``: prep -> step -> ``matcher.wait`` -> unpack).
+:func:`span` wraps each phase as a context manager and always does two
+things:
 
-Tracing is off by default and a disabled :func:`span` is a no-op context
-manager (one truthiness check), so the engines keep their spans in place
-permanently — including inside the host event loop — without a
-measurable cost.  The recorder is process-global and append-only between
-:func:`enable`/:func:`disable`; :func:`events` returns the raw list,
-:func:`to_chrome_trace` the JSON-ready document.
+* it enters ``jax.profiler.TraceAnnotation(name, **args)``.  With no
+  capture running that is one TraceMe check; inside a
+  ``jax.profiler.trace`` capture the span lands in the same
+  ``.xplane.pb`` as the device ops, on the profiler's clock, with
+  ``args`` as its stats (they are passed only through the annotation, so
+  nothing is formatted when no capture runs);
+* it appends ``(start_ns, dur_ns)`` from ``time.perf_counter_ns`` to a
+  bounded per-name record (the last :data:`RECORD_MAX` spans of each
+  name).  :func:`record` returns it as arrays, :func:`contained` finds
+  each span's children by time containment, :func:`breakdown` sums it by
+  name, and :func:`clear` empties it.  A parent is found by containment
+  on one thread: every program span is opened on the caller's thread.
+
+:func:`enable` additionally keeps each span as a Chrome trace-event
+``"X"`` (complete) event — microsecond timestamps, pid/tid — which
+:func:`save` writes as a JSON file loadable in ``chrome://tracing`` or
+https://ui.perfetto.dev.  That list is off by default.
 """
 
 from __future__ import annotations
 
-import contextlib
+import collections
 import json
 import os
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Deque, Dict, List, Tuple
+
+import numpy as np
+
+#: Spans of one name the in-memory record keeps (the newest).
+RECORD_MAX = 65536
 
 _enabled = False
 _events: List[Dict] = []
-_t0 = 0.0
+_t0_ns = 0
 _lock = threading.Lock()
-_annotation_cls = None
-_annotation_missing = False
+_record: Dict[str, Deque[Tuple[int, int]]] = {}
+_profiler = None
+_clock = time.perf_counter_ns
 
 
-def _annotation(name: str):
-    """``jax.profiler.TraceAnnotation`` when available, else a null ctx."""
-    global _annotation_cls, _annotation_missing
-    if _annotation_missing:
-        return contextlib.nullcontext()
-    if _annotation_cls is None:
-        try:
-            from jax.profiler import TraceAnnotation
-            _annotation_cls = TraceAnnotation
-        except Exception:
-            _annotation_missing = True
-            return contextlib.nullcontext()
-    return _annotation_cls(name)
+def _annotation(name: str, args: Dict):
+    """``jax.profiler.TraceAnnotation(name, **args)``, looked up on the
+    module each time so that a test can stand in for it."""
+    global _profiler
+    if _profiler is None:
+        import jax.profiler
+
+        _profiler = jax.profiler
+    return _profiler.TraceAnnotation(name, **args)
 
 
-def enable(clear: bool = True) -> None:
-    """Start recording spans (optionally clearing previous events).
-
-    Also installs the :func:`install_jax_monitoring` listeners (once per
-    process, best-effort) so traced runs pick up persistent-cache
-    hit/miss and backend compile-time events without extra wiring.
-    """
-    global _enabled, _t0
-    with _lock:
-        if clear:
-            _events.clear()
-        _t0 = time.perf_counter()
-        _enabled = True
-    install_jax_monitoring()
+def enable() -> None:
+    """Also keep each span as a Chrome event, timed from now."""
+    global _enabled, _t0_ns
+    _t0_ns = _clock()
+    _enabled = True
 
 
 def disable() -> None:
@@ -72,45 +72,54 @@ def disable() -> None:
     _enabled = False
 
 
-def enabled() -> bool:
-    return _enabled
-
-
 def clear() -> None:
+    """Empty the Chrome event list and the in-memory record."""
     with _lock:
         _events.clear()
+        _record.clear()
 
 
-@contextlib.contextmanager
-def span(name: str, **args):
-    """One traced phase.  ``args`` become the event's ``args`` payload.
+class _Span:
+    __slots__ = ("name", "args", "_ann", "_start")
 
-    Disabled tracing short-circuits before any clock read; enabled spans
-    record a complete ("X") event and nest naturally by wall time —
-    Perfetto reconstructs the flame from overlapping [ts, ts+dur) ranges
-    on one tid.
-    """
-    if not _enabled:
-        yield
-        return
-    t_start = time.perf_counter()
-    with _annotation(name):
-        try:
-            yield
-        finally:
-            t_end = time.perf_counter()
+    def __init__(self, name: str, args: Dict):
+        self.name, self.args = name, args
+
+    def __enter__(self):
+        self._ann = _annotation(self.name, self.args)
+        self._ann.__enter__()
+        self._start = _clock()
+        return self
+
+    def __exit__(self, *exc):
+        dur = _clock() - self._start
+        self._ann.__exit__(*exc)
+        rec = _record.get(self.name)
+        if rec is None:
+            rec = _record.setdefault(
+                self.name, collections.deque(maxlen=RECORD_MAX))
+        rec.append((self._start, dur))
+        if _enabled:
             ev = {
-                "name": name,
+                "name": self.name,
                 "ph": "X",
-                "ts": (t_start - _t0) * 1e6,
-                "dur": (t_end - t_start) * 1e6,
+                "ts": (self._start - _t0_ns) * 1e-3,
+                "dur": dur * 1e-3,
                 "pid": os.getpid(),
                 "tid": threading.get_ident(),
             }
-            if args:
-                ev["args"] = {k: _jsonable(v) for k, v in args.items()}
+            if self.args:
+                ev["args"] = {k: _jsonable(v) for k, v in self.args.items()}
             with _lock:
                 _events.append(ev)
+        return False
+
+
+def span(name: str, **args) -> _Span:
+    """One phase of the program: a ``TraceAnnotation`` and an entry of the
+    in-memory record, always; a Chrome event while :func:`enable` is on.
+    Spans nest by wall time on one thread."""
+    return _Span(name, args)
 
 
 def _jsonable(v):
@@ -119,8 +128,41 @@ def _jsonable(v):
     return repr(v)
 
 
+def record() -> Dict[str, Tuple[np.ndarray, np.ndarray]]:
+    """``{name: (starts_ns, durs_ns)}`` of the recorded spans, int64
+    arrays in the order the spans closed."""
+    with _lock:
+        snap = {k: list(v) for k, v in _record.items()}
+    out = {}
+    for name, rows in snap.items():
+        a = np.asarray(rows, np.int64).reshape(-1, 2)
+        out[name] = (a[:, 0], a[:, 1])
+    return out
+
+
+def contained(parent: str, child: str, rec=None):
+    """Each ``parent`` span's duration and the summed duration and count
+    of the ``child`` spans inside it (by time containment; both from
+    :func:`record`, or ``rec``).  Returns three arrays over the parent
+    spans in start order: ``(parent_ns, child_ns, n_child)``; an empty
+    triple when no parent was recorded."""
+    rec = record() if rec is None else rec
+    empty = np.zeros(0, np.int64)
+    if parent not in rec:
+        return empty, empty, empty
+    ps, pd = rec[parent]
+    order = np.argsort(ps, kind="stable")
+    ps, pd = ps[order], pd[order]
+    cs, cd = rec.get(child, (empty, empty))
+    k = np.searchsorted(ps, cs, side="right") - 1
+    inside = (k >= 0) & (cs + cd <= (ps + pd)[np.maximum(k, 0)])
+    k, w = k[inside], cd[inside]
+    child_ns = np.bincount(k, weights=w, minlength=ps.size)
+    return pd, child_ns.astype(np.int64), np.bincount(k, minlength=ps.size)
+
+
 def events() -> List[Dict]:
-    """The recorded events (shared list snapshot)."""
+    """The Chrome events kept while enabled (a snapshot)."""
     with _lock:
         return list(_events)
 
@@ -141,115 +183,13 @@ def save(path: str) -> str:
     return path
 
 
-def instant(name: str, **args) -> None:
-    """Record an instant ("i") event — a point-in-time marker with an
-    args payload (dispatch cost stats, cache hit/miss notifications)."""
-    if not _enabled:
-        return
-    ev = {
-        "name": name,
-        "ph": "i",
-        "s": "p",
-        "ts": (time.perf_counter() - _t0) * 1e6,
-        "pid": os.getpid(),
-        "tid": threading.get_ident(),
-    }
-    if args:
-        ev["args"] = {k: _jsonable(v) for k, v in args.items()}
-    with _lock:
-        _events.append(ev)
-
-
-def dispatch_cost(name: str, jitted, *args, **kwargs) -> Optional[Dict]:
-    """Attach the compiled dispatch's XLA cost analysis to the trace.
-
-    Lowers+compiles ``jitted`` for ``args`` (a persistent-compilation-
-    cache hit when the engines already compiled it this process) and
-    records flops / bytes-accessed / memory footprints as an instant
-    event named ``<name>.cost``.  Best-effort across jax versions:
-    returns the stat dict, or ``None`` when tracing is disabled or the
-    AOT cost APIs are unavailable — never raises into the engine.
-    """
-    if not _enabled:
-        return None
-    try:
-        compiled = jitted.lower(*args, **kwargs).compile()
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        stats: Dict[str, float] = {}
-        for key in ("flops", "bytes accessed", "optimal_seconds"):
-            v = ca.get(key) if hasattr(ca, "get") else None
-            if isinstance(v, (int, float)):
-                stats[key.replace(" ", "_")] = float(v)
-        try:
-            mem = compiled.memory_analysis()
-            for attr in ("output_size_in_bytes", "temp_size_in_bytes",
-                         "argument_size_in_bytes"):
-                v = getattr(mem, attr, None)
-                if isinstance(v, (int, float)):
-                    stats[attr] = float(v)
-        except Exception:
-            pass
-    except Exception:
-        return None
-    instant(f"{name}.cost", **stats)
-    return stats
-
-
-_monitoring_installed: Optional[bool] = None
-
-
-def install_jax_monitoring() -> bool:
-    """Forward ``jax.monitoring`` events into the trace — persistent
-    compilation-cache hits/misses and backend compile-time durations
-    become instant/complete events next to the engine spans.
-
-    Idempotent and best-effort (the monitoring API and its event names
-    vary across jax versions); listeners record nothing while tracing
-    is disabled.  Returns whether a listener is installed.
-    """
-    global _monitoring_installed
-    if _monitoring_installed is not None:
-        return _monitoring_installed
-    try:
-        from jax import monitoring
-
-        def _keep(event: str) -> bool:
-            return ("compilation_cache" in event
-                    or "backend_compile" in event)
-
-        def _on_event(event: str, **kw) -> None:
-            if _enabled and _keep(event):
-                instant("jax" + event.replace("/", "."))
-
-        def _on_duration(event: str, duration: float, **kw) -> None:
-            if _enabled and _keep(event):
-                instant("jax" + event.replace("/", "."),
-                        duration_s=float(duration))
-
-        monitoring.register_event_listener(_on_event)
-        monitoring.register_event_duration_secs_listener(_on_duration)
-        _monitoring_installed = True
-    except Exception:  # pragma: no cover - jax without monitoring
-        _monitoring_installed = False
-    return _monitoring_installed
-
-
-def breakdown(evs: Optional[List[Dict]] = None) -> Dict[str, Dict]:
-    """Aggregate events by span name: count, total/mean duration (us).
-
-    The span table of the run report (``tools/obs_report.py``); also a
-    convenient assertion surface for tests.
-    """
-    evs = events() if evs is None else evs
+def breakdown() -> Dict[str, Dict]:
+    """The record summed by span name: count, total and mean duration
+    (us).  The span table of ``tools/check_policy_budget.py``; also an
+    assertion surface for tests."""
     out: Dict[str, Dict] = {}
-    for ev in evs:
-        row = out.setdefault(
-            ev["name"], {"count": 0, "total_us": 0.0}
-        )
-        row["count"] += 1
-        row["total_us"] += float(ev.get("dur", 0.0))
-    for row in out.values():
-        row["mean_us"] = row["total_us"] / max(row["count"], 1)
+    for name, (_starts, durs) in record().items():
+        total = float(durs.sum()) * 1e-3
+        out[name] = {"count": int(durs.size), "total_us": total,
+                     "mean_us": total / max(durs.size, 1)}
     return out

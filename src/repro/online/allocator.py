@@ -62,6 +62,7 @@ import numpy as np
 from repro.core import isc, matching, regression
 from repro.core.matching import IDLE_COST
 from repro.core.synpa import Scheduler, make_fused_step
+from repro.obs import trace as obs_trace
 
 Pair = Tuple[int, int]
 
@@ -303,6 +304,17 @@ class StreamingAllocator(OnlinePolicy):
     # ------------------------------------------------------------- pairing
     def pair(self, q, active, counters, ran, arrived, departed,
              prev_pairs, prev_solo, hints=None):
+        with obs_trace.span("alloc.pair", q=q, n_active=len(active)):
+            return self._pair(q, active, counters, ran, arrived, departed,
+                              prev_pairs, prev_solo, hints)
+
+    def _pair(self, q, active, counters, ran, arrived, departed,
+              prev_pairs, prev_solo, hints):
+        """One decision, in the spans a trace splits it into: ``alloc.prep``
+        (masks and hint scatter), ``alloc.step`` (the fused step with its
+        argument transfers), ``matcher.wait`` (the device matcher's fetch,
+        in :func:`repro.core.matching.device_pairs`) and ``alloc.unpack``
+        (vertices back to slots)."""
         active = np.asarray(active, np.int64)
         arrived_set = set(int(s) for s in arrived)
         capacity = int(counters.shape[0])
@@ -312,40 +324,41 @@ class StreamingAllocator(OnlinePolicy):
             self._ensure_state(capacity)
             self._apply_hints(hints, arrived_set)
             return self._random_pairing(active)
-        self._ensure_state(capacity)
-
-        # --- Build the fused-dispatch masks from the previous quantum.
-        partner = np.arange(capacity, dtype=np.int32)
-        masks = np.zeros((4, capacity), bool)   # solve, solo, valid, fresh
-        if prev_pairs:
-            pp = np.asarray(prev_pairs, np.int64).reshape(-1, 2)
-            both_ran = ran[pp[:, 0]] & ran[pp[:, 1]]
-            pa, pb = pp[both_ran, 0], pp[both_ran, 1]
-            partner[pa], partner[pb] = pb, pa
-            masks[0, pa] = masks[0, pb] = True
-        if prev_solo is not None and ran[prev_solo]:
-            masks[1, prev_solo] = True
-        masks[2, active] = True
-        if arrived_set:
-            masks[3, list(arrived_set)] = True
-        hinted = self._apply_hints(hints, arrived_set)
-        if hinted:
-            # A hinted newcomer scores with its profiled stack, not the
-            # uniform placeholder: keep the fused step from resetting it.
-            masks[3, hinted] = False
+        with obs_trace.span("alloc.prep"):
+            self._ensure_state(capacity)
+            # --- Build the fused-dispatch masks from the previous quantum.
+            partner = np.arange(capacity, dtype=np.int32)
+            masks = np.zeros((4, capacity), bool)  # solve, solo, valid, fresh
+            if prev_pairs:
+                pp = np.asarray(prev_pairs, np.int64).reshape(-1, 2)
+                both_ran = ran[pp[:, 0]] & ran[pp[:, 1]]
+                pa, pb = pp[both_ran, 0], pp[both_ran, 1]
+                partner[pa], partner[pb] = pb, pa
+                masks[0, pa] = masks[0, pb] = True
+            if prev_solo is not None and ran[prev_solo]:
+                masks[1, prev_solo] = True
+            masks[2, active] = True
+            if arrived_set:
+                masks[3, list(arrived_set)] = True
+            hinted = self._apply_hints(hints, arrived_set)
+            if hinted:
+                # A hinted newcomer scores with its profiled stack, not the
+                # uniform placeholder: keep the fused step from resetting it.
+                masks[3, hinted] = False
         a_count = int(active.size)
         odd = a_count % 2 == 1
 
         # --- Steps 0-2 + cost prep: one device dispatch, one transfer back.
         # The ST estimate state stays on the device: the returned ``st``
         # feeds the next quantum's call directly.
-        cost_dev, self._st = self._step(
-            np.asarray(counters, np.float32),
-            partner,
-            self._st,
-            masks,
-            odd,
-        )
+        with obs_trace.span("alloc.step"):
+            cost_dev, self._st = self._step(
+                np.asarray(counters, np.float32),
+                partner,
+                self._st,
+                masks,
+                odd,
+            )
 
         if a_count == 1:
             return [], int(active[0])
@@ -362,13 +375,14 @@ class StreamingAllocator(OnlinePolicy):
             pairs_v = matching.device_pairs(
                 cost_dev, valid, eps=self.cfg.refine_eps
             )
-            out: List[Pair] = []
-            solo: Optional[int] = None
-            for x, y in pairs_v:
-                if capacity in (x, y):
-                    solo = x if y == capacity else y
-                else:
-                    out.append((x, y))
+            with obs_trace.span("alloc.unpack"):
+                out: List[Pair] = []
+                solo: Optional[int] = None
+                for x, y in pairs_v:
+                    if capacity in (x, y):
+                        solo = x if y == capacity else y
+                    else:
+                        out.append((x, y))
             return out, solo
 
         # --- Step 3: (incremental) matching on the compact active set.

@@ -185,114 +185,128 @@ def run_device_sim_batched(sims: Sequence, n_quanta: int,
     ``app_telemetry`` (implies ``telemetry``) attaches each lane's
     per-application ring as ``OnlineStats.app_telemetry`` — per-lane
     rings are bit-identical to the single-dispatch twin's.
-    """
-    telemetry = telemetry or app_telemetry
-    assert len(sims) >= 1, "batched run needs at least one scenario lane"
-    base = sims[0]
-    spec: ScanPolicy = base.policy
-    params = base.machine.params
-    c = base.capacity
-    statics = _spec_statics(spec)
-    for s in sims:
-        assert s.engine == "scan", "batched lanes must be scan-engine sims"
-        assert s.policy.kind in DEVICE_SIM_KINDS, s.policy.kind
-        assert s.capacity == c, (
-            f"lane capacity mismatch: {s.capacity} != {c}"
-        )
-        assert s.machine.params == params, "lane machine params differ"
-        assert _spec_statics(s.policy) == statics, (
-            "batched lanes must share policy statics (method/model by "
-            f"identity): {s.policy} vs {spec}"
-        )
-        assert s.tables is base.tables, (
-            "batched lanes must share one profiled PhaseTables instance"
-        )
 
+    Spans: ``batch_sim.run`` around the call, and as its children, one
+    after another, ``batch_sim.presample`` (lane checks and arrival
+    pre-sampling), ``.pack`` (the lanes' inputs stacked, the race looked
+    up or built), ``.commit`` (the host-to-device transfers),
+    ``.compile`` (the warm-up), ``.dispatch`` (each run, to
+    ``block_until_ready``), ``.fetch`` and ``.stats`` (the job records).
+    """
+    with obs_trace.span("batch_sim.run", lanes=len(sims), quanta=n_quanta):
+        return _run_batched(sims, n_quanta, repeats, transfer_guard, warmup,
+                            telemetry or app_telemetry, app_telemetry)
+
+
+def _run_batched(sims, n_quanta, repeats, transfer_guard, warmup,
+                 telemetry, app_telemetry) -> List[OnlineStats]:
     with obs_trace.span("batch_sim.presample", lanes=len(sims),
                         quanta=n_quanta):
+        assert len(sims) >= 1, "batched run needs at least one scenario lane"
+        base = sims[0]
+        spec: ScanPolicy = base.policy
+        params = base.machine.params
+        c = base.capacity
+        statics = _spec_statics(spec)
+        for s in sims:
+            assert s.engine == "scan", "batched lanes must be scan-engine sims"
+            assert s.policy.kind in DEVICE_SIM_KINDS, s.policy.kind
+            assert s.capacity == c, (
+                f"lane capacity mismatch: {s.capacity} != {c}"
+            )
+            assert s.machine.params == params, "lane machine params differ"
+            assert _spec_statics(s.policy) == statics, (
+                "batched lanes must share policy statics (method/model by "
+                f"identity): {s.policy} vs {spec}"
+            )
+            assert s.tables is base.tables, (
+                "batched lanes must share one profiled PhaseTables instance"
+            )
+
         preps = [_prepare_inputs(s, n_quanta) for s in sims]
-    L = len(sims)
-    j_pad = max(p["j_pad"] for p in preps)
-    faulted_lane = [p["fcfg"] is not None for p in preps]
-    faulted = any(faulted_lane)
+    with obs_trace.span("batch_sim.pack", lanes=len(sims)):
+        L = len(sims)
+        j_pad = max(p["j_pad"] for p in preps)
+        faulted_lane = [p["fcfg"] is not None for p in preps]
+        faulted = any(faulted_lane)
 
-    # Synergy tables ship once; fifo lanes' selected path never reads
-    # them, so sharing is value-neutral — but synergy lanes must agree.
-    syn_lanes = [i for i, s in enumerate(sims) if s.admission == "synergy"]
-    if syn_lanes:
-        p0 = preps[syn_lanes[0]]
-        syn_cost, syn_mean = p0["syn_cost"], p0["syn_mean"]
-        syn_stacks = p0["syn_stacks"]
-        for i in syn_lanes[1:]:
-            assert (
-                np.array_equal(preps[i]["syn_cost"], syn_cost)
-                and np.array_equal(preps[i]["syn_mean"], syn_mean)
-                and np.array_equal(preps[i]["syn_stacks"], syn_stacks)
-            ), "synergy lanes must share admission tables"
-    else:
-        syn_cost = preps[0]["syn_cost"]
-        syn_mean = preps[0]["syn_mean"]
-        syn_stacks = preps[0]["syn_stacks"]
+        # Synergy tables ship once; fifo lanes' selected path never reads
+        # them, so sharing is value-neutral — but synergy lanes must agree.
+        syn_lanes = [i for i, s in enumerate(sims) if s.admission == "synergy"]
+        if syn_lanes:
+            p0 = preps[syn_lanes[0]]
+            syn_cost, syn_mean = p0["syn_cost"], p0["syn_mean"]
+            syn_stacks = p0["syn_stacks"]
+            for i in syn_lanes[1:]:
+                assert (
+                    np.array_equal(preps[i]["syn_cost"], syn_cost)
+                    and np.array_equal(preps[i]["syn_mean"], syn_mean)
+                    and np.array_equal(preps[i]["syn_stacks"], syn_stacks)
+                ), "synergy lanes must share admission tables"
+        else:
+            syn_cost = preps[0]["syn_cost"]
+            syn_mean = preps[0]["syn_mean"]
+            syn_stacks = preps[0]["syn_stacks"]
 
-    job_pool = np.stack(
-        [_repad(p["job_pool"], j_pad, 0) for p in preps]
-    )
-    job_arrive = np.stack(
-        [_repad(p["job_arrive"], j_pad, n_quanta) for p in preps]
-    )
-    job_target = np.stack(
-        [_repad(p["job_target"], j_pad, np.inf) for p in preps]
-    )
-    mkeys = np.stack(
-        [np.asarray(jax.random.PRNGKey(s.seed)) for s in sims]
-    )
-    is_syn = np.array(
-        [s.admission == "synergy" for s in sims], dtype=bool
-    )
-    if faulted:
-        # Unfaulted lanes ride an all-up unit-speed schedule: eviction
-        # never fires and the speed multiply is exactly 1.0f — values
-        # stay bit-identical to the multiply-free single-lane graph.
-        fup = np.stack([
-            p["fup"] if f else np.ones((n_quanta, c), bool)
-            for p, f in zip(preps, faulted_lane)
-        ])
-        fspeed = np.stack([
-            p["fspeed"] if f else np.ones((n_quanta, c), np.float32)
-            for p, f in zip(preps, faulted_lane)
-        ])
-        max_retries = np.array([
-            p["fcfg"][0] if f else 0
-            for p, f in zip(preps, faulted_lane)
-        ], np.int32)
-        backoff = np.array([
-            p["fcfg"][1] if f else 0
-            for p, f in zip(preps, faulted_lane)
-        ], np.int32)
-        preserve = np.array([
-            bool(p["fcfg"][2]) if f else True
-            for p, f in zip(preps, faulted_lane)
-        ], bool)
-    else:
-        fup = fspeed = max_retries = backoff = preserve = None
+        job_pool = np.stack(
+            [_repad(p["job_pool"], j_pad, 0) for p in preps]
+        )
+        job_arrive = np.stack(
+            [_repad(p["job_arrive"], j_pad, n_quanta) for p in preps]
+        )
+        job_target = np.stack(
+            [_repad(p["job_target"], j_pad, np.inf) for p in preps]
+        )
+        mkeys = np.stack(
+            [np.asarray(jax.random.PRNGKey(s.seed)) for s in sims]
+        )
+        is_syn = np.array(
+            [s.admission == "synergy" for s in sims], dtype=bool
+        )
+        if faulted:
+            # Unfaulted lanes ride an all-up unit-speed schedule: eviction
+            # never fires and the speed multiply is exactly 1.0f — values
+            # stay bit-identical to the multiply-free single-lane graph.
+            fup = np.stack([
+                p["fup"] if f else np.ones((n_quanta, c), bool)
+                for p, f in zip(preps, faulted_lane)
+            ])
+            fspeed = np.stack([
+                p["fspeed"] if f else np.ones((n_quanta, c), np.float32)
+                for p, f in zip(preps, faulted_lane)
+            ])
+            max_retries = np.array([
+                p["fcfg"][0] if f else 0
+                for p, f in zip(preps, faulted_lane)
+            ], np.int32)
+            backoff = np.array([
+                p["fcfg"][1] if f else 0
+                for p, f in zip(preps, faulted_lane)
+            ], np.int32)
+            preserve = np.array([
+                bool(p["fcfg"][2]) if f else True
+                for p, f in zip(preps, faulted_lane)
+            ], bool)
+        else:
+            fup = fspeed = max_retries = backoff = preserve = None
 
-    key = _batch_key(spec, c, n_quanta, j_pad, telemetry, faulted,
-                     app_telemetry=app_telemetry)
-    ent = _BATCH_CACHE.get(key)
-    if ent is None:
-        with obs_trace.span("batch_sim.compile_build", capacity=c,
-                            quanta=n_quanta, lanes=L,
-                            app_telemetry=app_telemetry):
-            ent = (spec.method, spec.model, _build_batched_race(
-                spec, params, c, n_quanta, j_pad, telemetry, faulted,
-                app_telemetry=app_telemetry,
-            ))
-        _BATCH_CACHE[key] = ent
-        while len(_BATCH_CACHE) > _BATCH_CACHE_MAX:
-            _BATCH_CACHE.popitem(last=False)
-    else:
-        _BATCH_CACHE.move_to_end(key)
-    race = ent[2]
+        key = _batch_key(spec, c, n_quanta, j_pad, telemetry, faulted,
+                         app_telemetry=app_telemetry)
+        ent = _BATCH_CACHE.get(key)
+        if ent is None:
+            with obs_trace.span("batch_sim.compile_build", capacity=c,
+                                quanta=n_quanta, lanes=L,
+                                app_telemetry=app_telemetry):
+                ent = (spec.method, spec.model, _build_batched_race(
+                    spec, params, c, n_quanta, j_pad, telemetry, faulted,
+                    app_telemetry=app_telemetry,
+                ))
+            _BATCH_CACHE[key] = ent
+            while len(_BATCH_CACHE) > _BATCH_CACHE_MAX:
+                _BATCH_CACHE.popitem(last=False)
+        else:
+            _BATCH_CACHE.move_to_end(key)
+        race = ent[2]
 
     with obs_trace.span("batch_sim.commit", lanes=L):
         dev = lambda a: jax.device_put(jnp.asarray(a))  # noqa: E731
@@ -311,7 +325,6 @@ def run_device_sim_batched(sims: Sequence, n_quanta: int,
     if warmup:
         with obs_trace.span("batch_sim.compile", lanes=L):
             out = jax.block_until_ready(race(*args))
-        obs_trace.dispatch_cost("batch_sim.race", race, *args)
     walls = []
     for _ in range(max(int(repeats), 1)):
         t0 = time.perf_counter()
@@ -322,27 +335,27 @@ def run_device_sim_batched(sims: Sequence, n_quanta: int,
             else:
                 out = jax.block_until_ready(race(*args))
         walls.append(time.perf_counter() - t0)
-    # Per-scenario cost: the grid is indivisible, so each lane carries
-    # an equal share of the whole-grid median wall.
-    per_quantum = float(np.median(walls)) / max(L * n_quanta, 1)
 
     with obs_trace.span("batch_sim.fetch", lanes=L):
         fetched = tuple(np.asarray(o) for o in out)
-    admit, finish, queue_depth, n_active, n_solo = fetched[:5]
-    fi = 5
-    retries = retry_at = evictions = requeues = None
-    if faulted:
-        retries, retry_at, evictions, requeues = fetched[fi:fi + 4]
-        fi += 4
-    tlm = app_tlm = None
-    if telemetry:
-        tlm = fetched[fi]
-        fi += 1
-    if app_telemetry:
-        app_tlm = fetched[fi]
+        admit, finish, queue_depth, n_active, n_solo = fetched[:5]
+        fi = 5
+        retries = retry_at = evictions = requeues = None
+        if faulted:
+            retries, retry_at, evictions, requeues = fetched[fi:fi + 4]
+            fi += 4
+        tlm = app_tlm = None
+        if telemetry:
+            tlm = fetched[fi]
+            fi += 1
+        if app_telemetry:
+            app_tlm = fetched[fi]
 
     stats_out: List[OnlineStats] = []
     with obs_trace.span("batch_sim.stats", lanes=L):
+        # Per-scenario cost: the grid is indivisible, so each lane carries
+        # an equal share of the whole-grid median wall.
+        per_quantum = float(np.median(walls)) / max(L * n_quanta, 1)
         for i, (sim, prep) in enumerate(zip(sims, preps)):
             j = prep["j"]
             arrive_q, pids = prep["arrive_q"], prep["pids"]

@@ -310,6 +310,7 @@ def _make_open_ops(spec: ScanPolicy, params, capacity: int, j_pad: int,
         )
 
     # ------------------------------------------------ open machine quantum
+    @jax.named_scope("machine")
     def open_quantum(dt, aid, active, phase_idx, phase_left, progress,
                      target, partner, mkey, q, speed=None):
         """Membership-masked quantum: the in-graph
@@ -355,6 +356,7 @@ def _make_open_ops(spec: ScanPolicy, params, capacity: int, j_pad: int,
         return counters, after, done, frac, new_idx, new_left
 
     # ------------------------------------------------- telemetry shadow
+    @jax.named_scope("telemetry")
     def open_slow_stats(dt, aid, active, phase_idx, partner,
                         per_ctx: bool = False):
         """``[mean, max]`` realized slowdown over the active contexts —
@@ -391,150 +393,152 @@ def _make_open_ops(spec: ScanPolicy, params, capacity: int, j_pad: int,
     def body(dt, job_pool, job_arrive, job_target, syn_cost, syn_mean,
              syn_stacks, mkey, fup, fspeed, lane_cfg, carry_t, q):
         carry, fc = carry_t
-        # 1. Arrivals: the queue tail is a masked count over the sorted
-        # job array — no state to update.
-        tail = jnp.sum(job_arrive <= q).astype(jnp.int32)
+        with jax.named_scope("admission"):
+            # 1. Arrivals: the queue tail is a masked count over the sorted
+            # job array — no state to update.
+            tail = jnp.sum(job_arrive <= q).astype(jnp.int32)
 
-        app_id, job_at = carry.app_id, carry.job_at
-        if faults:
-            # 1b. Fault eviction: jobs on cores that are down this quantum
-            # leave *before* admission (the host heartbeat order).  A core
-            # stays masked while down, so only transition quanta evict.
-            # Lane mode reads the retry knobs off the per-lane config —
-            # they only enter comparisons and adds, so traced scalars
-            # reproduce the static graph's values exactly.
-            if lane_faults:
-                max_retries = lane_cfg.max_retries
-                backoff = lane_cfg.backoff
+            app_id, job_at = carry.app_id, carry.job_at
+            if faults:
+                # 1b. Fault eviction: jobs on cores that are down this quantum
+                # leave *before* admission (the host heartbeat order).  A core
+                # stays masked while down, so only transition quanta evict.
+                # Lane mode reads the retry knobs off the per-lane config —
+                # they only enter comparisons and adds, so traced scalars
+                # reproduce the static graph's values exactly.
+                if lane_faults:
+                    max_retries = lane_cfg.max_retries
+                    backoff = lane_cfg.backoff
+                else:
+                    max_retries, backoff = s_max_retries, s_backoff
+                upq = fup[q]
+                speedq = fspeed[q]
+                evict = (app_id >= 0) & ~upq
+                ej = jnp.where(evict, job_at, j_pad)
+                ej_safe = jnp.clip(ej, 0, j_pad - 1)
+                retries = fc.retries.at[ej].add(1, mode="drop")
+                over = retries[ej_safe] > max_retries
+                requeue_c = evict & ~over     # dropped past max_retries
+                retry_at = fc.retry_at.at[
+                    jnp.where(requeue_c, ej, j_pad)
+                ].set(q + backoff, mode="drop")
+                if lane_faults:
+                    saved_val = jnp.where(
+                        lane_cfg.preserve, carry.progress, 0.0
+                    )
+                else:
+                    saved_val = carry.progress if s_preserve else jnp.zeros(
+                        c, jnp.float32
+                    )
+                saved = fc.saved.at[ej].set(saved_val, mode="drop")
+                n_evict = jnp.sum(evict).astype(jnp.int32)
+                app_id = jnp.where(evict, -1, app_id)
+                job_at = jnp.where(evict, -1, job_at)
+
+            # 2. Admission into free contexts (FIFO dequeue order either way).
+            if faults:
+                free = (app_id < 0) & upq
+                # 2a. Retry pool ahead of the fresh queue: the r-th eligible
+                # victim (ascending job id) re-enters on the r-th lowest free
+                # up context — the host rule as a rank-matching scatter.
+                elig = retry_at <= q
+                n_take = jnp.minimum(jnp.sum(elig), jnp.sum(free)).astype(
+                    jnp.int32
+                )
+                erank = jnp.cumsum(elig.astype(jnp.int32)) - 1
+                take_j = elig & (erank < n_take)
+                job_of_rank = jnp.full(c, j_pad, jnp.int32).at[
+                    jnp.where(take_j, erank, c)
+                ].set(jnp.arange(j_pad, dtype=jnp.int32), mode="drop")
+                frank = jnp.cumsum(free.astype(jnp.int32)) - 1
+                rtake = free & (frank < n_take)
+                jr = jnp.where(rtake, job_of_rank[jnp.clip(frank, 0, c - 1)],
+                               j_pad)
+                app_id = jnp.where(
+                    rtake, job_pool[jnp.clip(jr, 0, j_pad - 1)], app_id
+                )
+                job_at = jnp.where(rtake, jr, job_at)
+                retry_at = retry_at.at[jnp.where(rtake, jr, j_pad)].set(
+                    RETRY_NEVER, mode="drop"
+                )
+                n_requeue = jnp.sum(rtake).astype(jnp.int32)
+                free = free & ~rtake
             else:
-                max_retries, backoff = s_max_retries, s_backoff
-            upq = fup[q]
-            speedq = fspeed[q]
-            evict = (app_id >= 0) & ~upq
-            ej = jnp.where(evict, job_at, j_pad)
-            ej_safe = jnp.clip(ej, 0, j_pad - 1)
-            retries = fc.retries.at[ej].add(1, mode="drop")
-            over = retries[ej_safe] > max_retries
-            requeue_c = evict & ~over     # dropped past max_retries
-            retry_at = fc.retry_at.at[
-                jnp.where(requeue_c, ej, j_pad)
-            ].set(q + backoff, mode="drop")
-            if lane_faults:
-                saved_val = jnp.where(
-                    lane_cfg.preserve, carry.progress, 0.0
+                free = app_id < 0
+            if admission == "synergy":
+                app_id, job_at, took_f, head = admit_synergy(
+                    app_id, job_at, carry.head, tail, job_pool,
+                    syn_cost, syn_mean,
+                )
+            elif lane:
+                # Both rules run every quantum; the per-lane flag selects.
+                # The un-selected rule's outputs are dead values, so fifo
+                # lanes are value-independent of the (shared) synergy tables.
+                s_app, s_job, s_took, s_head = admit_synergy(
+                    app_id, job_at, carry.head, tail, job_pool,
+                    syn_cost, syn_mean, trip_gate=lane_cfg.is_syn,
+                )
+                f_app, f_job, f_took, f_head = admit_fifo(
+                    app_id, job_at, free, carry.head, tail, job_pool,
+                )
+                is_syn = lane_cfg.is_syn
+                app_id = jnp.where(is_syn, s_app, f_app)
+                job_at = jnp.where(is_syn, s_job, f_job)
+                took_f = jnp.where(is_syn, s_took, f_took)
+                head = jnp.where(is_syn, s_head, f_head)
+            else:
+                app_id, job_at, took_f, head = admit_fifo(
+                    app_id, job_at, free, carry.head, tail, job_pool,
+                )
+            # ``took`` covers every newly-placed context (fresh + retry) —
+            # slot-state reset and the policy's fresh mask; ``took_f`` is the
+            # fresh subset — queue head/admit_q/admission counts stay
+            # first-admission-only so queue identities keep holding.
+            took = (took_f | rtake) if faults else took_f
+            jidx = jnp.where(took, job_at, j_pad)
+            target = jnp.where(
+                took, job_target[jnp.clip(jidx, 0, j_pad - 1)], carry.target
+            )
+            phase_idx = jnp.where(took, 0, carry.phase_idx)
+            phase_left = jnp.where(
+                took, dt.duration[jnp.maximum(app_id, 0), 0], carry.phase_left
+            )
+            if faults:
+                # Re-admissions restart at phase 0 with saved (or zero)
+                # progress; fresh admissions start from zero as always.
+                progress = jnp.where(
+                    rtake, saved[jnp.clip(jidx, 0, j_pad - 1)],
+                    jnp.where(took_f, 0.0, carry.progress),
                 )
             else:
-                saved_val = carry.progress if s_preserve else jnp.zeros(
-                    c, jnp.float32
+                progress = jnp.where(took, 0.0, carry.progress)
+            # Fresh admissions are exactly the contiguous queue window
+            # [carry.head, head) of the sorted job array (both admission
+            # rules dequeue in arrival order; retries don't move the head),
+            # so the admit log is a vectorized range select — the equivalent
+            # scatter over per-slot job indices lowers to a serial
+            # per-source loop on XLA:CPU and serializes across lanes under
+            # vmap.  Values are identical.
+            jobs_idx = jnp.arange(j_pad, dtype=jnp.int32)
+            admit_q = jnp.where(
+                (jobs_idx >= carry.head) & (jobs_idx < head), q, carry.admit_q
+            )
+            st = carry.st
+            if use_hints:
+                # ST-hint seeding: a newcomer's estimate is its profiled
+                # solo stack, not the uniform placeholder (fresh-mask
+                # skipped below).  Lane mode masks the hint to synergy
+                # lanes — fifo lanes keep the uniform start and the
+                # fresh-solve path.
+                hint_m = (took & lane_cfg.is_syn) if lane else took
+                st = jnp.where(
+                    hint_m[:, None], syn_stacks[jnp.maximum(app_id, 0)], st
                 )
-            saved = fc.saved.at[ej].set(saved_val, mode="drop")
-            n_evict = jnp.sum(evict).astype(jnp.int32)
-            app_id = jnp.where(evict, -1, app_id)
-            job_at = jnp.where(evict, -1, job_at)
 
-        # 2. Admission into free contexts (FIFO dequeue order either way).
-        if faults:
-            free = (app_id < 0) & upq
-            # 2a. Retry pool ahead of the fresh queue: the r-th eligible
-            # victim (ascending job id) re-enters on the r-th lowest free
-            # up context — the host rule as a rank-matching scatter.
-            elig = retry_at <= q
-            n_take = jnp.minimum(jnp.sum(elig), jnp.sum(free)).astype(
-                jnp.int32
-            )
-            erank = jnp.cumsum(elig.astype(jnp.int32)) - 1
-            take_j = elig & (erank < n_take)
-            job_of_rank = jnp.full(c, j_pad, jnp.int32).at[
-                jnp.where(take_j, erank, c)
-            ].set(jnp.arange(j_pad, dtype=jnp.int32), mode="drop")
-            frank = jnp.cumsum(free.astype(jnp.int32)) - 1
-            rtake = free & (frank < n_take)
-            jr = jnp.where(rtake, job_of_rank[jnp.clip(frank, 0, c - 1)],
-                           j_pad)
-            app_id = jnp.where(
-                rtake, job_pool[jnp.clip(jr, 0, j_pad - 1)], app_id
-            )
-            job_at = jnp.where(rtake, jr, job_at)
-            retry_at = retry_at.at[jnp.where(rtake, jr, j_pad)].set(
-                RETRY_NEVER, mode="drop"
-            )
-            n_requeue = jnp.sum(rtake).astype(jnp.int32)
-            free = free & ~rtake
-        else:
-            free = app_id < 0
-        if admission == "synergy":
-            app_id, job_at, took_f, head = admit_synergy(
-                app_id, job_at, carry.head, tail, job_pool,
-                syn_cost, syn_mean,
-            )
-        elif lane:
-            # Both rules run every quantum; the per-lane flag selects.
-            # The un-selected rule's outputs are dead values, so fifo
-            # lanes are value-independent of the (shared) synergy tables.
-            s_app, s_job, s_took, s_head = admit_synergy(
-                app_id, job_at, carry.head, tail, job_pool,
-                syn_cost, syn_mean, trip_gate=lane_cfg.is_syn,
-            )
-            f_app, f_job, f_took, f_head = admit_fifo(
-                app_id, job_at, free, carry.head, tail, job_pool,
-            )
-            is_syn = lane_cfg.is_syn
-            app_id = jnp.where(is_syn, s_app, f_app)
-            job_at = jnp.where(is_syn, s_job, f_job)
-            took_f = jnp.where(is_syn, s_took, f_took)
-            head = jnp.where(is_syn, s_head, f_head)
-        else:
-            app_id, job_at, took_f, head = admit_fifo(
-                app_id, job_at, free, carry.head, tail, job_pool,
-            )
-        # ``took`` covers every newly-placed context (fresh + retry) —
-        # slot-state reset and the policy's fresh mask; ``took_f`` is the
-        # fresh subset — queue head/admit_q/admission counts stay
-        # first-admission-only so queue identities keep holding.
-        took = (took_f | rtake) if faults else took_f
-        jidx = jnp.where(took, job_at, j_pad)
-        target = jnp.where(
-            took, job_target[jnp.clip(jidx, 0, j_pad - 1)], carry.target
-        )
-        phase_idx = jnp.where(took, 0, carry.phase_idx)
-        phase_left = jnp.where(
-            took, dt.duration[jnp.maximum(app_id, 0), 0], carry.phase_left
-        )
-        if faults:
-            # Re-admissions restart at phase 0 with saved (or zero)
-            # progress; fresh admissions start from zero as always.
-            progress = jnp.where(
-                rtake, saved[jnp.clip(jidx, 0, j_pad - 1)],
-                jnp.where(took_f, 0.0, carry.progress),
-            )
-        else:
-            progress = jnp.where(took, 0.0, carry.progress)
-        # Fresh admissions are exactly the contiguous queue window
-        # [carry.head, head) of the sorted job array (both admission
-        # rules dequeue in arrival order; retries don't move the head),
-        # so the admit log is a vectorized range select — the equivalent
-        # scatter over per-slot job indices lowers to a serial
-        # per-source loop on XLA:CPU and serializes across lanes under
-        # vmap.  Values are identical.
-        jobs_idx = jnp.arange(j_pad, dtype=jnp.int32)
-        admit_q = jnp.where(
-            (jobs_idx >= carry.head) & (jobs_idx < head), q, carry.admit_q
-        )
-        st = carry.st
-        if use_hints:
-            # ST-hint seeding: a newcomer's estimate is its profiled solo
-            # stack, not the uniform placeholder (fresh-mask skipped below).
-            # Lane mode masks the hint to synergy lanes — fifo lanes keep
-            # the uniform start and the fresh-solve path.
-            hint_m = (took & lane_cfg.is_syn) if lane else took
-            st = jnp.where(
-                hint_m[:, None], syn_stacks[jnp.maximum(app_id, 0)], st
-            )
-
-        active = app_id >= 0
-        n_active = jnp.sum(active).astype(jnp.int32)
-        odd = (n_active % 2) == 1
-        queue_depth = tail - head
+            active = app_id >= 0
+            n_active = jnp.sum(active).astype(jnp.int32)
+            odd = (n_active % 2) == 1
+            queue_depth = tail - head
 
         # 3. Policy: pair the active population off the *previous*
         # quantum's counters (the host event-loop order).
@@ -547,67 +551,71 @@ def _make_open_ops(spec: ScanPolicy, params, capacity: int, j_pad: int,
                 # No predictor/matcher in play: policy fields are zero.
                 pol_diag = jnp.zeros(7, jnp.float32)
         else:
-            solve = carry.ran & (carry.partner_prev != idx)
-            solo_m = carry.ran & (carry.partner_prev == idx)
-            if lane:
-                # Hinted (synergy) lanes skip the fresh solve; fifo lanes
-                # flag newcomers — the two static graphs, selected per lane.
-                fresh = jnp.where(lane_cfg.is_syn, False, took)
-            else:
-                fresh = jnp.zeros(c, bool) if use_hints else took
-            masks = jnp.stack([solve, solo_m, active, fresh])
-            if telemetry:
-                cost, st, fdiag = fstep(carry.counters, carry.partner_prev,
-                                        st, masks, odd)
-            else:
-                cost, st = fstep(carry.counters, carry.partner_prev, st,
-                                 masks, odd)
-            valid_p = jnp.zeros(p, bool).at[:c].set(active).at[c].set(odd)
-            if spec.matcher == "full":
-                matched = matching.device_pairs_partner(
-                    cost, valid_p, eps=spec.refine_eps,
-                    max_rounds=full_budget, with_rounds=telemetry,
-                )
-                if telemetry:
-                    mpart, rounds = matched
-                    # A full re-match rebuilds every pair: the whole
-                    # valid population counts as dirty.
-                    dirty = jnp.sum(valid_p.astype(jnp.float32))
+            with jax.named_scope("synpa_step"):
+                solve = carry.ran & (carry.partner_prev != idx)
+                solo_m = carry.ran & (carry.partner_prev == idx)
+                if lane:
+                    # Hinted (synergy) lanes skip the fresh solve; fifo
+                    # lanes flag newcomers — the two static graphs,
+                    # selected per lane.
+                    fresh = jnp.where(lane_cfg.is_syn, False, took)
                 else:
-                    mpart = matched
-            else:
-                matched = matching.device_repair_partner(
-                    cost, carry.mpart, valid_p, eps=spec.refine_eps,
-                    max_rounds=spec.refine_rounds, with_diag=telemetry,
-                )
+                    fresh = jnp.zeros(c, bool) if use_hints else took
+                masks = jnp.stack([solve, solo_m, active, fresh])
                 if telemetry:
-                    mpart, rounds, nd = matched
-                    dirty = nd.astype(jnp.float32)
+                    cost, st, fdiag = fstep(carry.counters, carry.partner_prev,
+                                            st, masks, odd)
                 else:
-                    mpart = matched
-            if telemetry:
-                n_valid = jnp.maximum(
-                    jnp.sum(valid_p.astype(jnp.float32)), 1.0
-                )
-                # Mean predicted cost per committed pair (each pair's
-                # entry appears twice over n_valid/2 pairs; factors of 2
-                # cancel).
-                gathered = jnp.where(
-                    valid_p, cost[jnp.arange(p), mpart], 0.0
-                )
-                pred = jnp.sum(gathered) / n_valid
-                pol_diag = jnp.concatenate([
-                    jnp.stack([pred, dirty, rounds.astype(jnp.float32)]),
-                    fdiag,
-                ])
-                if app_telemetry:
-                    # Per-context predicted slowdown: cost[i, j] is
-                    # slowdown(i|j) + slowdown(j|i), so a context's own
-                    # share of its committed pair is half its gathered
-                    # entry (masked to co-running contexts when the ring
-                    # row is built below).
-                    pred_ctx = gathered[:c] * 0.5
-            partner = jnp.where(active, _machine_partner_of(mpart, c), idx)
+                    cost, st = fstep(carry.counters, carry.partner_prev, st,
+                                     masks, odd)
+                valid_p = jnp.zeros(p, bool).at[:c].set(active).at[c].set(odd)
+                if spec.matcher == "full":
+                    matched = matching.device_pairs_partner(
+                        cost, valid_p, eps=spec.refine_eps,
+                        max_rounds=full_budget, with_rounds=telemetry,
+                    )
+                    if telemetry:
+                        mpart, rounds = matched
+                        # A full re-match rebuilds every pair: the whole
+                        # valid population counts as dirty.
+                        dirty = jnp.sum(valid_p.astype(jnp.float32))
+                    else:
+                        mpart = matched
+                else:
+                    matched = matching.device_repair_partner(
+                        cost, carry.mpart, valid_p, eps=spec.refine_eps,
+                        max_rounds=spec.refine_rounds, with_diag=telemetry,
+                    )
+                    if telemetry:
+                        mpart, rounds, nd = matched
+                        dirty = nd.astype(jnp.float32)
+                    else:
+                        mpart = matched
+                with jax.named_scope("telemetry"):
+                    if telemetry:
+                        n_valid = jnp.maximum(
+                            jnp.sum(valid_p.astype(jnp.float32)), 1.0
+                        )
+                        # Mean predicted cost per committed pair (each
+                        # pair's entry appears twice over n_valid/2 pairs;
+                        # factors of 2 cancel).
+                        gathered = jnp.where(
+                            valid_p, cost[jnp.arange(p), mpart], 0.0
+                        )
+                        pred = jnp.sum(gathered) / n_valid
+                        pol_diag = jnp.concatenate([
+                            jnp.stack([pred, dirty,
+                                       rounds.astype(jnp.float32)]),
+                            fdiag,
+                        ])
+                        if app_telemetry:
+                            # Per-context predicted slowdown: cost[i, j] is
+                            # slowdown(i|j) + slowdown(j|i), so a context's own
+                            # share of its committed pair is half its
+                            # gathered entry (masked to co-running contexts
+                            # when the ring row is built below).
+                            pred_ctx = gathered[:c] * 0.5
+                partner = jnp.where(active, _machine_partner_of(mpart, c), idx)
 
         # 4. One membership-masked machine quantum + 5. departures.
         if app_telemetry:
@@ -627,98 +635,100 @@ def _make_open_ops(spec: ScanPolicy, params, capacity: int, j_pad: int,
             dt, app_id, active, phase_idx, phase_left, progress, target,
             partner, mkey, q, speed=speedq if faults else None,
         )
-        if segment:
-            # Checkpoint variant: the finish log must live in the carry
-            # (snapshots restore it), so it keeps the per-quantum
-            # scatter.  Values match the streamed variant exactly.
-            finish_q = carry.finish_q.at[
-                jnp.where(done, job_at, j_pad)
-            ].set(q.astype(jnp.float32) + frac, mode="drop")
-        else:
-            # One-dispatch variant: a (J,)-indexed scatter per quantum
-            # lowers to a serial per-source loop on XLA:CPU and
-            # serializes across lanes under vmap — so the finish events
-            # ride the scan ``ys`` as (slot-indexed job, value) pairs
-            # and ``unpack`` rebuilds the log once post-scan with a
-            # sort + binary-search gather.  Carry value is untouched.
-            finish_q = carry.finish_q
-        fin_j = jnp.where(done, job_at, j_pad)
-        fin_v = q.astype(jnp.float32) + frac
-        n_solo = jnp.sum(active & (partner == idx)).astype(jnp.int32)
-        new = _OpenCarry(
-            app_id=jnp.where(done, -1, app_id),
-            job_at=jnp.where(done, -1, job_at),
-            phase_idx=phase_idx,
-            phase_left=phase_left,
-            progress=after,
-            target=jnp.where(done, jnp.inf, target),
-            head=head,
-            counters=counters,
-            ran=active,
-            partner_prev=partner,
-            mpart=mpart,
-            st=st,
-            admit_q=admit_q,
-            finish_q=finish_q,
-        )
-        fc_new = _FaultCarry(
-            retries=retries, retry_at=retry_at, saved=saved
-        ) if faults else None
+        with jax.named_scope("admission"):
+            if segment:
+                # Checkpoint variant: the finish log must live in the carry
+                # (snapshots restore it), so it keeps the per-quantum
+                # scatter.  Values match the streamed variant exactly.
+                finish_q = carry.finish_q.at[
+                    jnp.where(done, job_at, j_pad)
+                ].set(q.astype(jnp.float32) + frac, mode="drop")
+            else:
+                # One-dispatch variant: a (J,)-indexed scatter per quantum
+                # lowers to a serial per-source loop on XLA:CPU and
+                # serializes across lanes under vmap — so the finish events
+                # ride the scan ``ys`` as (slot-indexed job, value) pairs
+                # and ``unpack`` rebuilds the log once post-scan with a
+                # sort + binary-search gather.  Carry value is untouched.
+                finish_q = carry.finish_q
+            fin_j = jnp.where(done, job_at, j_pad)
+            fin_v = q.astype(jnp.float32) + frac
+            n_solo = jnp.sum(active & (partner == idx)).astype(jnp.int32)
+            new = _OpenCarry(
+                app_id=jnp.where(done, -1, app_id),
+                job_at=jnp.where(done, -1, job_at),
+                phase_idx=phase_idx,
+                phase_left=phase_left,
+                progress=after,
+                target=jnp.where(done, jnp.inf, target),
+                head=head,
+                counters=counters,
+                ran=active,
+                partner_prev=partner,
+                mpart=mpart,
+                st=st,
+                admit_q=admit_q,
+                finish_q=finish_q,
+            )
+            fc_new = _FaultCarry(
+                retries=retries, retry_at=retry_at, saved=saved
+            ) if faults else None
         outs = (queue_depth, n_active, n_solo)
         if not segment:
             outs = outs + (fin_j, fin_v)
         if faults:
             outs = outs + (n_evict, n_requeue)
-        if telemetry:
-            f32 = lambda v: v.astype(jnp.float32)  # noqa: E731
-            # ``done`` is derived from a float comparison, and *any*
-            # in-graph consumer of it (a sum, even a barrier) hands the
-            # quantum's float subgraph a different fusion and costs the
-            # run its bit-identity — so the departures column is left
-            # zero here and filled host-side from the fetched finish
-            # log (``run_device_sim``), where it is exactly
-            # ``bincount(floor(finish_q))``.  The fault columns follow the
-            # same doctrine (zeros in-graph, host-filled): failures/
-            # recoveries/straggling are pure schedule data, and eviction/
-            # requeue counts already ride the ``ys`` as integers.
-            tvec = jnp.concatenate([
-                jnp.stack([
-                    f32(head), f32(tail), f32(queue_depth),
-                    f32(jnp.sum(took_f)), jnp.float32(0.0),
-                    f32(n_active), f32(n_solo),
-                    slow_mean, slow_max,
-                ]),
-                pol_diag,
-                jnp.zeros(5, jnp.float32),
-            ])
-            outs = outs + (tvec,)
-        if app_telemetry:
-            # Per-app ring row: identities and ground truth off the
-            # barrier shadow, prediction off the policy's cost gather,
-            # ST stacks off the policy carry.  Empty contexts record
-            # app_id -1 and zeros.
-            co_ctx = partner_app >= 0
-            # Barriers: the residual must combine the *recorded*
-            # (rounded) tensors, not FMA-fused upstream products.
-            pred_col, real_col = lax.optimization_barrier(
-                (jnp.where(co_ctx, pred_ctx, 0.0), ratio_ctx))
-            resid_col = jnp.where(pred_col > 0.0, pred_col - real_col,
-                                  0.0)
-            st4 = st[:, :APP_ST_WIDTH]
-            if st4.shape[1] < APP_ST_WIDTH:
-                st4 = jnp.concatenate(
-                    [st4, jnp.zeros((c, APP_ST_WIDTH - st4.shape[1]),
-                                    jnp.float32)], axis=1)
-            st4 = jnp.where((aid_ctx >= 0)[:, None], st4, 0.0)
-            avec = jnp.concatenate([
-                jnp.stack([
-                    aid_ctx.astype(jnp.float32),
-                    partner_app.astype(jnp.float32),
-                    pred_col, real_col, resid_col,
-                ], axis=1),
-                st4,
-            ], axis=1)
-            outs = outs + (avec,)
+        with jax.named_scope("telemetry"):
+            if telemetry:
+                f32 = lambda v: v.astype(jnp.float32)  # noqa: E731
+                # ``done`` is derived from a float comparison, and *any*
+                # in-graph consumer of it (a sum, even a barrier) hands the
+                # quantum's float subgraph a different fusion and costs the
+                # run its bit-identity — so the departures column is left
+                # zero here and filled host-side from the fetched finish
+                # log (``run_device_sim``), where it is exactly
+                # ``bincount(floor(finish_q))``.  The fault columns follow the
+                # same doctrine (zeros in-graph, host-filled): failures/
+                # recoveries/straggling are pure schedule data, and eviction/
+                # requeue counts already ride the ``ys`` as integers.
+                tvec = jnp.concatenate([
+                    jnp.stack([
+                        f32(head), f32(tail), f32(queue_depth),
+                        f32(jnp.sum(took_f)), jnp.float32(0.0),
+                        f32(n_active), f32(n_solo),
+                        slow_mean, slow_max,
+                    ]),
+                    pol_diag,
+                    jnp.zeros(5, jnp.float32),
+                ])
+                outs = outs + (tvec,)
+            if app_telemetry:
+                # Per-app ring row: identities and ground truth off the
+                # barrier shadow, prediction off the policy's cost gather,
+                # ST stacks off the policy carry.  Empty contexts record
+                # app_id -1 and zeros.
+                co_ctx = partner_app >= 0
+                # Barriers: the residual must combine the *recorded*
+                # (rounded) tensors, not FMA-fused upstream products.
+                pred_col, real_col = lax.optimization_barrier(
+                    (jnp.where(co_ctx, pred_ctx, 0.0), ratio_ctx))
+                resid_col = jnp.where(pred_col > 0.0, pred_col - real_col,
+                                      0.0)
+                st4 = st[:, :APP_ST_WIDTH]
+                if st4.shape[1] < APP_ST_WIDTH:
+                    st4 = jnp.concatenate(
+                        [st4, jnp.zeros((c, APP_ST_WIDTH - st4.shape[1]),
+                                        jnp.float32)], axis=1)
+                st4 = jnp.where((aid_ctx >= 0)[:, None], st4, 0.0)
+                avec = jnp.concatenate([
+                    jnp.stack([
+                        aid_ctx.astype(jnp.float32),
+                        partner_app.astype(jnp.float32),
+                        pred_col, real_col, resid_col,
+                    ], axis=1),
+                    st4,
+                ], axis=1)
+                outs = outs + (avec,)
         return (new, fc_new), outs
 
     def carry0():
@@ -1055,7 +1065,6 @@ def run_device_sim(sim, n_quanta: int, repeats: int = 1,
     if warmup:
         with obs_trace.span("device_sim.compile"):
             out = jax.block_until_ready(race(*args))  # compile + first run
-        obs_trace.dispatch_cost("device_sim.race", race, *args)
     walls = []
     for _ in range(max(int(repeats), 1)):
         t0 = time.perf_counter()

@@ -131,19 +131,20 @@ class DeviceTables:
     @classmethod
     def build(cls, tables: PhaseTables) -> "DeviceTables":
         f = lambda a: jnp.asarray(a, jnp.float32)  # noqa: E731
-        return cls(
-            n_apps=tables.n_apps,
-            n_phases=jnp.asarray(tables.n_phases, jnp.int32),
-            comps=f(tables.comps),
-            util=f(tables.util),
-            x_fe=f(tables.x_fe),
-            x_be=f(tables.x_be),
-            duration=f(tables.duration),
-            omega=f(tables.omega),
-            retire=f(tables.retire),
-            mem_sens=f(tables.mem_sens),
-            fetch_sens=f(tables.fetch_sens),
-        )
+        with obs_trace.span("scan.tables", n_apps=tables.n_apps):
+            return cls(
+                n_apps=tables.n_apps,
+                n_phases=jnp.asarray(tables.n_phases, jnp.int32),
+                comps=f(tables.comps),
+                util=f(tables.util),
+                x_fe=f(tables.x_fe),
+                x_be=f(tables.x_be),
+                duration=f(tables.duration),
+                omega=f(tables.omega),
+                retire=f(tables.retire),
+                mem_sens=f(tables.mem_sens),
+                fetch_sens=f(tables.fetch_sens),
+            )
 
 
 jax.tree_util.register_pytree_node(
@@ -299,6 +300,7 @@ def _make_machine_quantum(dt: DeviceTables, params: MachineParams):
     idx = jnp.arange(n, dtype=jnp.int32)
     cycles = jnp.float32(params.quantum_cycles)
 
+    @jax.named_scope("machine")
     def quantum(state: _MachineState, partner, mkey, q):
         ph = state.phase_idx % dt.n_phases
         comps = _corun_components_scan(dt, ph, partner, params)
@@ -470,6 +472,7 @@ def _make_policy_step(spec: ScanPolicy, n: int, p_pad: int,
     p_idx = jnp.arange(p_pad, dtype=jnp.int32)
     n_valid = jnp.maximum(jnp.sum(valid_p.astype(jnp.float32)), 1.0)
 
+    @jax.named_scope("synpa_step")
     def step(q, counters, mpart, st, pkey, first=False):
         partner = _machine_partner_of(mpart, n)
         solve = partner != idx
@@ -501,21 +504,22 @@ def _make_policy_step(spec: ScanPolicy, n: int, p_pad: int,
                 max_rounds=spec.refine_rounds, with_rounds=telemetry,
             )
         if telemetry:
-            mpart, rounds = matched
-            # Mean predicted cost per committed pair: each pair's entry
-            # appears twice (i->j and j->i) over n_valid/2 pairs, so the
-            # two factors of 2 cancel.
-            gathered = jnp.where(valid_p, cost[p_idx, mpart], 0.0)
-            pred = jnp.sum(gathered) / n_valid
-            pol = jnp.concatenate(
-                [jnp.stack([pred, rounds.astype(jnp.float32)]), fdiag]
-            )
-            if app_telemetry:
-                # Per-slot predicted slowdown: cost[i, j] is
-                # slowdown(i|j) + slowdown(j|i), so each slot's share of
-                # its committed pair is half the gathered entry.
-                return mpart, st, pol, gathered[:n] * 0.5
-            return mpart, st, pol
+            with jax.named_scope("telemetry"):
+                mpart, rounds = matched
+                # Mean predicted cost per committed pair: each pair's entry
+                # appears twice (i->j and j->i) over n_valid/2 pairs, so the
+                # two factors of 2 cancel.
+                gathered = jnp.where(valid_p, cost[p_idx, mpart], 0.0)
+                pred = jnp.sum(gathered) / n_valid
+                pol = jnp.concatenate(
+                    [jnp.stack([pred, rounds.astype(jnp.float32)]), fdiag]
+                )
+                if app_telemetry:
+                    # Per-slot predicted slowdown: cost[i, j] is
+                    # slowdown(i|j) + slowdown(j|i), so each slot's share of
+                    # its committed pair is half the gathered entry.
+                    return mpart, st, pol, gathered[:n] * 0.5
+                return mpart, st, pol
         return matched, st
 
     return step
@@ -622,6 +626,7 @@ def build_race(
         )
         return jnp.concatenate([head, st4], axis=1)
 
+    @jax.named_scope("telemetry")
     def ring_rows(dt, phase_idx, partner, pol, pred_slot, st):
         """(scalar ring row, per-app ring block or None) for one quantum."""
         if app_telemetry:
@@ -798,7 +803,6 @@ def run_quanta_scan(
 
     with obs_trace.span("scan.compile"):
         out = jax.block_until_ready(race(*args))  # compile + first run
-    obs_trace.dispatch_cost("scan.race", race, *args)
     walls = []
     for _ in range(max(int(repeats), 1)):
         t0 = time.perf_counter()
@@ -920,7 +924,6 @@ def run_quanta_multi_batched(
 
     with obs_trace.span("scan.compile", lanes=S):
         out = jax.block_until_ready(batched(*args))
-    obs_trace.dispatch_cost("scan.race.batched", batched, *args)
     walls = []
     for _ in range(max(int(repeats), 1)):
         t0 = time.perf_counter()

@@ -109,6 +109,218 @@ class TestTrace:
         assert rows["step"]["total_us"] >= 0
 
 
+class TestSpanRecord:
+    """Spans are always on the profiler's clock and in the in-memory
+    record; the Chrome event list stays off unless enabled."""
+
+    def test_recorder_off_still_annotates_and_records(self, monkeypatch):
+        entered = []
+
+        class FakeAnnotation:
+            def __init__(self, name, **args):
+                self.name, self.args = name, args
+
+            def __enter__(self):
+                entered.append((self.name, self.args))
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", FakeAnnotation)
+        obs_trace.disable()
+        obs_trace.clear()
+        with obs_trace.span("outer", q=3):
+            with obs_trace.span("inner"):
+                pass
+        assert entered == [("outer", {"q": 3}), ("inner", {})]
+        rec = obs_trace.record()
+        assert set(rec) == {"outer", "inner"}
+        (os_, od), (is_, id_) = rec["outer"], rec["inner"]
+        assert os_.size == od.size == is_.size == id_.size == 1
+        assert os_[0] <= is_[0] and is_[0] + id_[0] <= os_[0] + od[0]
+        assert obs_trace.events() == []
+        parent, child, n = obs_trace.contained("outer", "inner")
+        assert n.tolist() == [1] and child.tolist() == id_.tolist()
+        assert parent.tolist() == od.tolist()
+
+    def test_record_is_bounded_and_cleared(self):
+        obs_trace.clear()
+        for _ in range(obs_trace.RECORD_MAX + 5):
+            with obs_trace.span("tick"):
+                pass
+        starts, durs = obs_trace.record()["tick"]
+        assert starts.size == durs.size == obs_trace.RECORD_MAX
+        assert obs_trace.breakdown()["tick"]["count"] == obs_trace.RECORD_MAX
+        obs_trace.clear()
+        assert obs_trace.record() == {}
+
+    def test_decision_spans_nest_in_a_capture(self, tmp_path):
+        """A CPU capture of device-matcher decisions: ``alloc.pair``
+        holds prep, step, ``matcher.wait`` and unpack on one thread, and
+        the matcher's ops start inside a decision on the same clock."""
+        from repro.online.allocator import StreamingAllocator, StreamingConfig
+
+        machine = mc.SMTMachine(mc.MachineParams(), seed=0)
+        pool = pool_profiles()
+
+        def run():
+            ClusterSim(
+                machine, pool, 4,
+                StreamingAllocator(isc.SYNPA4_R_FEBE, _toy_model(),
+                                   StreamingConfig(matcher="device")),
+                PoissonArrivals(rate=1.5, n_pool=len(pool)),
+                seed=5, target_scale=0.1,
+            ).run(4)
+
+        run()  # compile outside the capture
+        with jax.profiler.trace(str(tmp_path)):
+            run()
+        path = next(os.path.join(d, f) for d, _s, fs in os.walk(tmp_path)
+                    for f in fs if f.endswith(".xplane.pb"))
+        pd = jax.profiler.ProfileData.from_file(path)
+        names = ("alloc.pair", "alloc.prep", "alloc.step", "matcher.wait",
+                 "alloc.unpack")
+        lines, matcher_ops = {}, []
+        for plane in pd.planes:
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name in names:
+                        lines.setdefault(line.name, []).append(
+                            (ev.name, ev.start_ns,
+                             ev.start_ns + ev.duration_ns))
+                    elif any(k == "hlo_module" and "device_pairs" in str(v)
+                             for k, v in ev.stats):
+                        matcher_ops.append(ev.start_ns)
+        assert len(lines) == 1, lines.keys()
+        spans = next(iter(lines.values()))
+        pairs = [(s, e) for n, s, e in spans if n == "alloc.pair"]
+        assert pairs
+        for name in names[1:]:
+            kids = [(s, e) for n, s, e in spans if n == name]
+            assert kids, name
+            for s, e in kids:
+                assert any(ps <= s and e <= pe for ps, pe in pairs), name
+        assert matcher_ops
+        for t in matcher_ops:
+            assert any(ps <= t <= pe for ps, pe in pairs)
+
+    def test_batch_sim_children_tile_the_run(self, machine, pool):
+        """Every host statement of ``run_device_sim_batched`` lies in one
+        child span: the children's durations sum to the parent's."""
+        from repro.online.batch_sim import run_device_sim_batched
+        from repro.smt.machine import PhaseTables
+
+        spec = ScanPolicy(kind="synpa", method=isc.SYNPA4_R_FEBE,
+                          model=_toy_model())
+        tables = PhaseTables.build(pool)
+        sims = [ClusterSim(machine, pool, 4, spec,
+                           PoissonArrivals(rate=1.4, n_pool=len(pool)),
+                           seed=s, target_scale=0.1, tables=tables,
+                           engine="scan") for s in (3, 4)]
+        run_device_sim_batched(sims, 6, warmup=False)  # compile
+        obs_trace.clear()
+        run_device_sim_batched(sims, 6, warmup=False)
+        rec = obs_trace.record()
+        parent = rec["batch_sim.run"][1]
+        assert parent.size == 1
+        kids = sum(obs_trace.contained("batch_sim.run", "batch_sim." + k,
+                                       rec)[1].sum()
+                   for k in ("presample", "pack", "commit", "compile",
+                             "dispatch", "fetch", "stats"))
+        assert 0.95 * parent[0] <= kids <= parent[0]
+
+
+def test_scope_time_splits_self_time_by_innermost_scope():
+    """``tools/scope_time.py``: nested ops' self times sum to the busy
+    time, and each lands on the innermost known scope of its op_name."""
+    from tools.scope_time import busy_ns, op_name_of, scope_of, self_times
+
+    names = {"jit_race": {
+        "while.1": "jit(race)/while",
+        "fusion.2": "jit(race)/while/body/synpa_step/jit(step)/synpa_step/"
+                    "inverse/while/body/mul",
+        "fusion.3": "jit(race)/while/body/synpa_step/matcher/two_opt/add"}}
+    evs = [("while.1", 0.0, 100.0, {}, "jit_race"),
+           ("fusion.2", 10.0, 30.0, {}, "jit_race"),
+           ("fusion.3", 50.0, 40.0, {}, "jit_race"),
+           ("copy.9", 120.0, 10.0, {}, "jit_other")]
+    selfs = {ev[0]: t for ev, t in self_times(evs)}
+    assert selfs == {"while.1": 30.0, "fusion.2": 30.0, "fusion.3": 40.0,
+                     "copy.9": 10.0}
+    assert sum(selfs.values()) == busy_ns(evs) == 110.0
+    scopes = [scope_of(op_name_of(names, m, n)) for n, _s, _d, _st, m in evs]
+    assert scopes == ["unscoped", "inverse", "matcher/two_opt", "unscoped"]
+
+
+def _op_scopes(compiled_text: str) -> set:
+    """Every path component of the ``op_name`` metadata of an HLO text."""
+    import re
+
+    return {part for name in re.findall(r'op_name="([^"]*)"', compiled_text)
+            for part in name.split("/")}
+
+
+class TestDeviceScopes:
+    """The device layers carry stable ``jax.named_scope`` names in the
+    compiled program's op metadata, where a trace reduction finds them."""
+
+    def test_closed_race_scopes(self, machine):
+        from repro.smt.machine import PhaseTables
+        from repro.smt.scan_engine import DeviceTables, build_race
+
+        profs = workloads.scaled_workload(8, seed=3)
+        tables = PhaseTables.build(profs)
+        spec = ScanPolicy(kind="synpa", method=isc.SYNPA4_R_FEBE,
+                          model=_toy_model())
+        race = build_race(tables, machine.params, [spec], 4, telemetry=True)
+        p = 16
+        text = race.lower(
+            DeviceTables.build(tables), np.zeros((1, p), np.int32),
+            np.full((1, 8, 4), 0.25, np.float32), jax.random.PRNGKey(0),
+            jax.random.PRNGKey(1)).compile().as_text()
+        assert {"machine", "synpa_step", "isc", "inverse", "pair_cost",
+                "matcher", "seed", "two_opt", "telemetry"} <= \
+            _op_scopes(text)
+
+    def test_open_race_scopes(self, machine, pool):
+        from repro.online import device_sim
+        from repro.smt.scan_engine import DeviceTables
+
+        spec = ScanPolicy(kind="synpa", method=isc.SYNPA4_R_FEBE,
+                          model=_toy_model())
+        sim = ClusterSim(machine, pool, 4, spec,
+                         PoissonArrivals(rate=1.4, n_pool=len(pool)),
+                         seed=3, target_scale=0.1, engine="scan")
+        prep = device_sim._prepare_inputs(sim, 6)
+        race = device_sim._build_race(spec, machine.params, sim.capacity, 6,
+                                      prep["j_pad"], "fifo", telemetry=True)
+        text = race.lower(
+            DeviceTables.build(sim.tables), prep["job_pool"],
+            prep["job_arrive"], prep["job_target"], prep["syn_cost"],
+            prep["syn_mean"], prep["syn_stacks"],
+            jax.random.PRNGKey(0)).compile().as_text()
+        assert {"admission", "machine", "synpa_step", "isc", "inverse",
+                "pair_cost", "matcher", "repair", "two_opt",
+                "telemetry"} <= _op_scopes(text)
+
+    def test_fused_step_and_matcher_scopes(self):
+        from repro.core.synpa import fused_pad, make_fused_step
+
+        n = 8
+        p = fused_pad(n)
+        step = make_fused_step(isc.SYNPA4_R_FEBE, _toy_model())
+        text = step.lower(
+            np.ones((n, 5), np.float32), np.arange(n, dtype=np.int32),
+            np.full((n, 4), 0.25, np.float32), np.ones((4, n), bool),
+            False).compile().as_text()
+        assert {"synpa_step", "isc", "inverse", "pair_cost"} <= \
+            _op_scopes(text)
+        text = matching._device_pairs_jit.lower(
+            np.ones((p, p), np.float32), np.ones(p, bool), eps=1e-9,
+            max_rounds=None).compile().as_text()
+        assert {"matcher", "seed", "two_opt"} <= _op_scopes(text)
+
+
 # ------------------------------------------------------- telemetry ring API
 class TestTelemetryLog:
     def test_roundtrip_and_views(self):

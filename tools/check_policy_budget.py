@@ -163,13 +163,11 @@ def measure(record: bool = False) -> dict:
         )["synpa4-scan"]
         return res.machine_s_per_quantum * 1e6
 
-    # Trace the whole measurement: the span table gives the recorded
-    # compile/steady split (compile cost is real user-visible latency
-    # but must never leak into the steady medians the guard compares),
-    # and enabling tracing arms the dispatch-cost / jax.monitoring
-    # instants for free.
-    trace_was_on = obs_trace.enabled()
-    obs_trace.enable(clear=not trace_was_on)
+    # The span record gives the measurement's compile/steady split
+    # (compile cost is real user-visible latency but must never leak
+    # into the steady medians the guard compares): what it gains from
+    # here to the end.
+    spans_before = obs_trace.breakdown()
 
     samples: dict = {
         "stream_median_us": [],
@@ -254,14 +252,13 @@ def measure(record: bool = False) -> dict:
     # The compile/steady split: total wall spent in compile-tagged spans
     # across the measurement (a cold persistent cache pays it, a warm one
     # mostly skips it) next to the steady medians above.
-    bd = obs_trace.breakdown()
-    compile_rows = {k: v for k, v in bd.items() if "compile" in k}
+    compile_rows = [
+        (v["total_us"] - spans_before.get(k, {}).get("total_us", 0.0),
+         v["count"] - spans_before.get(k, {}).get("count", 0))
+        for k, v in obs_trace.breakdown().items() if "compile" in k]
     metrics["compile_total_ms"] = float(
-        sum(v["total_us"] for v in compile_rows.values()) / 1e3)
-    metrics["compile_spans"] = float(
-        sum(v["count"] for v in compile_rows.values()))
-    if not trace_was_on:
-        obs_trace.disable()
+        sum(us for us, _n in compile_rows) / 1e3)
+    metrics["compile_spans"] = float(sum(n for _us, n in compile_rows))
     return obs_metrics.export_run(
         name="policy_time_n256",
         engine="scan",
