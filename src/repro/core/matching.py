@@ -954,39 +954,65 @@ def device_two_opt_partner(cost, partner, valid, eps=1e-9,
     optimality (when the round budget did not cut the loop short first);
     the partner vector is bit-identical either way.
     """
-    q = partner.shape[0] // 2
+    p = partner.shape[0]
+    q = p // 2
     if max_rounds is None:
         max_rounds = q
+    # A non-finite entry times a one-hot zero is NaN and would poison the
+    # whole product below.  The contract keeps every valid-valid edge
+    # finite and ``ok_swap`` masks every delta touching a frozen pair, so
+    # the clamp cannot change a result.
     cost = cost.astype(jnp.float32)
+    cost = jnp.where(jnp.isfinite(cost), cost, BIG)
     i0, j0, movable = _partner_to_pair_arrays(partner, valid)
-    ok_swap = movable[:, None] & movable[None, :] & ~jnp.eye(q, dtype=bool)
+    eye = jnp.eye(q, dtype=bool)
+    ok_swap = movable[:, None] & movable[None, :] & ~eye
     rows = jnp.arange(q, dtype=jnp.int32)
+    verts = jnp.arange(p, dtype=jnp.int32)
+    hi = lax.Precision.HIGHEST
 
     def body(state):
         i, j, k, _improved = state
-        cur = cost[i, j]
-        alt1 = cost[i[:, None], i[None, :]] + cost[j[:, None], j[None, :]]
-        alt2 = cost[i[:, None], j[None, :]] + cost[j[:, None], i[None, :]]
+        # The round reads the cost matrix permuted into pair order,
+        # cp[r, c] = cost[perm[r], perm[c]], as two one-hot products on
+        # the MXU.  Per element, a gather with computed indices runs as a
+        # near-serial loop on the TPU; a one-hot row times an f32 value
+        # at HIGHEST is exact, so cp holds the very entries a gather
+        # would read and every delta below is the same bits.
+        perm = jnp.concatenate([i, j])
+        pm = (perm[:, None] == verts[None, :]).astype(jnp.float32)
+        cp = jnp.matmul(jnp.matmul(pm, cost, precision=hi), pm.T,
+                        precision=hi)
+        cur = jnp.sum(jnp.where(eye, cp[:q, q:], 0.0), axis=1)
+        alt1 = cp[:q, :q] + cp[q:, q:]
+        alt2 = cp[:q, q:] + cp[q:, :q]
         delta = jnp.minimum(alt1, alt2) - (cur[:, None] + cur[None, :])
         delta = jnp.where(ok_swap, delta, 0.0)
         best = jnp.argmin(delta, axis=1).astype(jnp.int32)
-        gain = delta[rows, best]
-        commit = (gain < -eps) & (best[best] == rows) & (rows < best)
-        b = best
-        ib, jb = i[b], j[b]
-        use1 = alt1[rows, b] <= alt2[rows, b]
+        gain = jnp.min(delta, axis=1)
+        # Reads "at best[r]" are masked reductions over the one-hot
+        # ``pick`` (one nonzero a row, so exact), not element gathers.
+        pick = best[:, None] == rows[None, :]
+
+        def at_best(v):
+            return jnp.sum(jnp.where(pick, v[None, :], 0), axis=1)
+
+        mutual = jnp.any(pick & pick.T, axis=1)         # best[best] == rows
+        commit = (gain < -eps) & mutual & (rows < best)
+        ib, jb = at_best(i), at_best(j)
+        use1 = jnp.any(pick & (alt1 <= alt2), axis=1)
         # Row a keeps i_a and takes i_b (alt1) or j_b (alt2); row b keeps
         # the old j_a as its i and j_b (alt1) or i_b (alt2) as its j.
-        # The row-b side is written by *gather*, not scatter: commits are
+        # The row-b side is read through best, not scattered: commits are
         # mutual (a < b = best[a], best[b] == a), so row r receives a
         # write exactly when its own best row commits back into it, and
-        # the written values are gatherable through best[r].  A scatter
+        # the written values are readable through best[r].  A scatter
         # with computed indices lowers to a serial per-element loop on
         # XLA:CPU — and serializes over lanes under vmap — while the
-        # gather/select form stays vectorized and writes the same values
+        # select form stays vectorized and writes the same values
         # (commit and recv rows are disjoint: a < b).
-        recv = commit[b] & (b[b] == rows)
-        use1_b = use1[b]
+        recv = mutual & jnp.any(pick & commit[None, :], axis=1)
+        use1_b = jnp.any(pick & use1[None, :], axis=1)
         i_n = jnp.where(recv, jb, i)
         j_n = jnp.where(commit, jnp.where(use1, ib, jb), j)
         j_n = jnp.where(recv, jnp.where(use1_b, j, i), j_n)

@@ -1,8 +1,11 @@
 """Property tests for the Blossom matching engine (paper §5.3 step 3)."""
 
+import functools
+
 import hypothesis
 import hypothesis.strategies as st
 import numpy as np
+import pytest
 
 from repro.core import matching
 
@@ -195,3 +198,139 @@ def test_device_two_opt_refines_without_breaking_matching():
     after = sum(cp[i, out[i]] for i in range(n)) / 2
     assert after <= before + 1e-6
     assert (out[:n] < n).all(), "valid never re-pairs into padding"
+
+
+# ---------------------------------------------------------------------------
+# Device 2-opt against a plain numpy reference of its parallel mutual-best
+# round, written from the docstring of ``device_two_opt_partner``: pairs
+# (i, j) with i < j in ascending order of i; per round the (P/2, P/2)
+# swap-delta matrix in f32, every pair picks its first best counterpart,
+# mutual picks with a gain beyond ``eps`` commit at once (row a keeps i_a
+# and takes i_b or j_b, row b keeps j_a and takes the other); the loop
+# stops after a round that commits nothing or at the round budget.  The
+# device form must return the same partner vector and round count.
+# ---------------------------------------------------------------------------
+def _two_opt_rounds_ref(cost, partner, valid, eps=1e-9, max_rounds=None):
+    c = np.asarray(cost, np.float32)
+    partner = np.asarray(partner)
+    p = partner.shape[0]
+    q = p // 2
+    if max_rounds is None:
+        max_rounds = q
+    i = np.flatnonzero(partner > np.arange(p))
+    j = partner[i]
+    movable = np.asarray(valid)[i]
+    ok = movable[:, None] & movable[None, :] & ~np.eye(q, dtype=bool)
+    rows = np.arange(q)
+    k, improved = 0, True
+    while improved and k < max_rounds:
+        with np.errstate(invalid="ignore", over="ignore"):
+            cur = c[i, j]
+            alt1 = c[np.ix_(i, i)] + c[np.ix_(j, j)]
+            alt2 = c[np.ix_(i, j)] + c[np.ix_(j, i)]
+            delta = np.minimum(alt1, alt2) - (cur[:, None] + cur[None, :])
+        delta = np.where(ok, delta, np.float32(0.0))
+        best = np.argmin(delta, axis=1)
+        gain = delta[rows, best]
+        commit = ((gain < np.float32(-eps)) & (best[best] == rows)
+                  & (rows < best))
+        i_n, j_n = i.copy(), j.copy()
+        for a in np.flatnonzero(commit):
+            b = best[a]
+            use1 = alt1[a, b] <= alt2[a, b]
+            j_n[a] = i[b] if use1 else j[b]
+            i_n[b] = j[a]
+            j_n[b] = j[b] if use1 else i[b]
+        i, j = i_n, j_n
+        k += 1
+        improved = bool(commit.any())
+    out = np.empty(p, np.int64)
+    out[i], out[j] = j, i
+    return out, k
+
+
+def _matcher_case(kind, p, seed):
+    """A padded (P, P) cost matrix, its valid mask and a random starting
+    involution (valid vertices among themselves, padding consecutively)."""
+    rng = np.random.default_rng(seed)
+    pad = 2 if p == 8 else 8
+    nv = p - pad
+    n = nv - 1 if kind == "odd_idle" else nv
+    if kind in ("odd_idle", "pmu", "pmu_ties"):
+        c = _pmu_shaped(rng, n)
+        if kind == "pmu_ties":
+            c = np.round(c, 1)         # clones tie exactly
+    else:
+        c = _sym_cost(rng, n, low=0.5)
+    cp, valid = _padded(c, n, p)
+    if kind == "odd_idle":
+        cp[n, :n] = matching.IDLE_COST
+        cp[:n, n] = matching.IDLE_COST
+        valid[n] = True
+    if kind == "pad_inf":
+        cp[nv::2, :] = np.inf          # padding rows of inf and of BIG
+        cp[:, nv::2] = np.inf
+    part = np.empty(p, np.int32)
+    order = rng.permutation(nv)
+    part[order[0::2]], part[order[1::2]] = order[1::2], order[0::2]
+    pads = np.arange(nv, p)
+    part[pads[0::2]], part[pads[1::2]] = pads[1::2], pads[0::2]
+    return cp.astype(np.float32), valid, part
+
+
+@pytest.mark.parametrize("kind", ["uniform", "odd_idle", "pad_inf", "pmu",
+                                  "pmu_ties"])
+@pytest.mark.parametrize("lanes", [1, 8])
+@pytest.mark.parametrize("p", [8, 136, 1032])
+def test_device_two_opt_matches_numpy_rounds(p, lanes, kind):
+    import jax
+    import jax.numpy as jnp
+
+    cases = [_matcher_case(kind, p, 1000 * p + 10 * lanes + s)
+             for s in range(lanes)]
+    cost, valid, part = (np.stack(x) for x in zip(*cases))
+    fn = functools.partial(matching.device_two_opt_partner, eps=1e-9,
+                           with_rounds=True)
+    if lanes > 1:
+        fn = jax.vmap(fn)
+    else:
+        cost, valid, part = cost[0], valid[0], part[0]
+    out, rounds = jax.jit(fn)(jnp.asarray(cost), jnp.asarray(part),
+                              jnp.asarray(valid))
+    out = np.asarray(out).reshape(lanes, p)
+    rounds = np.asarray(rounds).reshape(lanes)
+    for lane, (c, v, pt) in enumerate(cases):
+        want, want_k = _two_opt_rounds_ref(c, pt, v)
+        np.testing.assert_array_equal(out[lane], want, err_msg=f"lane {lane}")
+        assert rounds[lane] == want_k, (lane, rounds[lane], want_k)
+
+
+@pytest.mark.parametrize("kind", ["uniform", "odd_idle", "pmu_ties"])
+@pytest.mark.parametrize("entry", ["device_pairs", "repair"])
+def test_device_entries_match_numpy_rounds(entry, kind):
+    """The full entry (sort seed) and the churn repair (repair seed) run
+    the same rounds as the reference from the seed they built."""
+    import jax.numpy as jnp
+
+    p = 136
+    cost, valid, part = _matcher_case(kind, p, 7 + p)
+    if entry == "device_pairs":
+        seed = np.asarray(matching.device_seed_partner(
+            jnp.asarray(cost), jnp.asarray(valid)))
+        want, _ = _two_opt_rounds_ref(cost, seed, valid)
+        got = matching.device_pairs(cost, valid)
+        assert got == sorted((v, int(want[v])) for v in range(p)
+                             if valid[v] and v < want[v])
+        return
+    # Churn: six vertices depart, so the repair seed re-pairs the widows.
+    rng = np.random.default_rng(p)
+    now = valid.copy()
+    now[rng.choice(np.flatnonzero(valid), 6, replace=False)] = False
+    seed, _nd = matching._repair_seed(jnp.asarray(cost), jnp.asarray(part),
+                                      jnp.asarray(now))
+    want, want_k = _two_opt_rounds_ref(cost, np.asarray(seed), now)
+    out, k, _ = matching.device_repair_partner(
+        jnp.asarray(cost), jnp.asarray(part), jnp.asarray(now), eps=1e-9,
+        with_diag=True)
+    np.testing.assert_array_equal(np.asarray(out), want)
+    assert int(k) == want_k

@@ -8,6 +8,10 @@ import: only one process at a time may load the TPU library, and under
 several pytest workers only the worker that runs this file may try.
 """
 
+import functools
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -42,8 +46,8 @@ def one_chip():
             compilation_cache.reset_cache()
 
 
-def _spec(shape, sharding):
-    return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+def _spec(shape, sharding, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
 @pytest.mark.parametrize("n,n_valid", [
@@ -69,3 +73,39 @@ def test_vmapped_pair_costs_compiles_for_v5e(one_chip):
     compiled = fn.lower(_spec((4, 264, C), one_chip),
                         _spec((C, 4), one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _big_gathers(hlo_text, min_elems):
+    """Result shapes of the ``gather`` instructions (fused ones included)
+    in an optimized HLO module that hold ``min_elems`` elements or more."""
+    found = []
+    for m in re.finditer(r"= \w+\[([\d,]*)\]\S* gather\(", hlo_text):
+        dims = [int(d) for d in m.group(1).split(",") if d]
+        if math.prod(dims) >= min_elems:
+            found.append(m.group(0))
+    return found
+
+
+def test_device_matcher_has_no_element_gathers_for_v5e(one_chip):
+    """The 2-opt round reads the cost matrix through one-hot products on
+    the MXU; a gather of q*q elements with computed indices runs as a
+    near-serial loop on the TPU and must not come back.  Gathers of (P,)
+    vectors outside the loop may stay."""
+    from repro.core import matching
+
+    p, lanes = 136, 8
+    q = p // 2
+    two_opt = jax.jit(jax.vmap(functools.partial(
+        matching.device_two_opt_partner, with_rounds=True)))
+    lowered = [
+        two_opt.lower(_spec((lanes, p, p), one_chip),
+                      _spec((lanes, p), one_chip, jnp.int32),
+                      _spec((lanes, p), one_chip, jnp.bool_)),
+        matching._device_pairs_jit.lower(
+            _spec((p, p), one_chip), _spec((p,), one_chip, jnp.bool_),
+            eps=1e-9, max_rounds=None),
+    ]
+    for low in lowered:
+        hlo = low.compile().as_text()
+        assert "while" in hlo
+        assert _big_gathers(hlo, q * q) == []
