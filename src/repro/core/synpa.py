@@ -119,7 +119,8 @@ def make_fused_step(
       for slots without one); rows that do not solve pass through — callers
       feed the returned ``st`` straight back next quantum, so the estimate
       state never leaves the device;
-    * ``masks``     (4, n) bool — one packed host->device transfer, rows:
+    * ``masks``     (4 or 5, n) bool — one packed host->device transfer,
+      rows:
 
       0. *solve* — slot co-ran and its estimate should refresh;
       1. *solo*  — slot ran alone: its measured fractions *are* its ST
@@ -127,6 +128,11 @@ def make_fused_step(
       2. *valid* — slot hosts an active application;
       3. *fresh* — reset the slot to the uniform placeholder (an arrival
          whose first counters have not happened yet);
+      4. *hinted* (optional row) — the slot keeps its carried ``prev_st``
+         row whatever its previous occupant measured: an arrival seeded
+         with an admission hint, in a slot whose departed occupant's
+         counters would otherwise solve over it.  Four-row masks compile
+         the graph without this select;
 
     * ``idle``      bool scalar — augment the idle-context vertex (row
       ``n``) with :data:`repro.core.matching.IDLE_COST` edges.
@@ -243,6 +249,10 @@ def make_fused_step(
         # Arrivals reset to the uniform placeholder (their slot may carry a
         # departed occupant's estimate until their first counters land).
         st = jnp.where(fresh_mask[:, None], uniform[None, :], st)
+        if masks.shape[0] > 4:
+            # Hinted arrivals keep their seeded estimate: the solve above
+            # ran on the departed occupant's counters.
+            st = jnp.where(masks[4][:, None], prev_st, st)
 
         # Step 2: all-pairs Eq. 4 scoring on the padded stack matrix.
         stp = jnp.concatenate(

@@ -70,7 +70,10 @@ OPEN_FIELDS = (
     "pred_cost_mean",      # mean predicted pair slowdown of the matching
     "repair_dirty",        # churn-repair dirty vertices re-paired
     "two_opt_rounds",      # device-matcher parallel swap rounds
-) + FUSED_DIAG_FIELDS + FAULT_FIELDS
+) + FUSED_DIAG_FIELDS + FAULT_FIELDS + (
+    "syn_trips",           # synergy placement loop trips this quantum
+    "syn_beside",          # fresh placements beside an occupied core-mate
+)
 
 
 #: Per-application ring (``app_telemetry=True`` on either engine), one

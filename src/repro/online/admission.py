@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import isc, regression
+from repro.obs import trace as obs_trace
 
 
 class SynergyAdmission:
@@ -53,20 +54,28 @@ class SynergyAdmission:
         from repro.smt.workloads import solo_stack
 
         self.method = method
-        self.stacks = np.stack([
-            np.asarray(solo_stack(machine, p, method, quanta=quanta),
-                       np.float32)
-            for p in pool
-        ])
-        cost = regression.pair_cost_matrix(
-            model, jnp.asarray(self.stacks), impl="xla"
-        )
-        self.pool_cost = np.asarray(cost, np.float64)
+        with obs_trace.span("syn.tables", apps=len(pool), quanta=quanta):
+            self.stacks = np.stack([
+                np.asarray(solo_stack(machine, p, method, quanta=quanta),
+                           np.float32)
+                for p in pool
+            ])
+            # Elementwise Eq. 4 over broadcast stacks and a 4-term sum: no
+            # contraction, so no matmul precision applies on any backend.
+            # The matrix's diagonal is the matcher's no-self-pairing
+            # sentinel, but two jobs of one app do co-run: score the pool
+            # against a copy of itself and keep the off-diagonal block,
+            # whose diagonal is the real same-app pair cost.
+            n = len(pool)
+            twice = jnp.asarray(np.concatenate([self.stacks, self.stacks]))
+            cost = regression.pair_cost_matrix(model, twice, impl="xla")
+            self.pool_cost = np.asarray(cost[:n, n:], np.float64)
         # Expected pairing cost of each job type against a uniform random
-        # co-runner — the placement score of a context on an empty core.
-        off = ~np.eye(len(pool), dtype=bool)
+        # co-runner of another type — the placement score of a context on
+        # an empty core.
+        off = ~np.eye(n, dtype=bool)
         self.mean_cost = np.array([
-            self.pool_cost[k][off[k]].mean() for k in range(len(pool))
+            self.pool_cost[k][off[k]].mean() for k in range(n)
         ])
 
     def place(self, pid: int, free_slots: Sequence[int],

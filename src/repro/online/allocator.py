@@ -343,8 +343,12 @@ class StreamingAllocator(OnlinePolicy):
             hinted = self._apply_hints(hints, arrived_set)
             if hinted:
                 # A hinted newcomer scores with its profiled stack, not the
-                # uniform placeholder: keep the fused step from resetting it.
+                # uniform placeholder or its slot's departed occupant's
+                # solve: the fifth mask row keeps the seeded estimate.
                 masks[3, hinted] = False
+                keep = np.zeros((1, capacity), bool)
+                keep[0, hinted] = True
+                masks = np.concatenate([masks, keep])
         a_count = int(active.size)
         odd = a_count % 2 == 1
 

@@ -95,7 +95,8 @@ from repro.smt.scan_engine import DeviceTables, ScanPolicy
 
 def _build_batched_race(spec: ScanPolicy, params, capacity: int,
                         n_quanta: int, j_pad: int, telemetry: bool,
-                        faulted: bool, app_telemetry: bool = False):
+                        faulted: bool, app_telemetry: bool = False,
+                        slot_log: bool = False):
     """One jitted, lane-batched open-system race.
 
     ``race(dt, syn_cost, syn_mean, syn_stacks, job_pool (L, J),
@@ -109,6 +110,7 @@ def _build_batched_race(spec: ScanPolicy, params, capacity: int,
     body, carry0, unpack = _make_open_ops(
         spec, params, capacity, j_pad, "lane", telemetry,
         "lane" if faulted else None, app_telemetry=app_telemetry,
+        slot_log=slot_log,
     )
 
     def lane_race(dt, syn_cost, syn_mean, syn_stacks, job_pool,
@@ -141,12 +143,12 @@ _BATCH_CACHE_MAX = 8
 
 def _batch_key(spec: ScanPolicy, capacity: int, n_quanta: int, j_pad: int,
                telemetry: bool, faulted: bool,
-               app_telemetry: bool = False) -> Tuple:
+               app_telemetry: bool = False, slot_log: bool = False) -> Tuple:
     return (
         spec.kind, id(spec.method), id(spec.model), spec.pair_impl,
         spec.solver, spec.matcher, spec.refine_eps, spec.refine_rounds,
         spec.first_match, capacity, n_quanta, j_pad, telemetry, faulted,
-        app_telemetry,
+        app_telemetry, slot_log,
     )
 
 
@@ -168,6 +170,7 @@ def run_device_sim_batched(sims: Sequence, n_quanta: int,
                            warmup: bool = True,
                            telemetry: bool = False,
                            app_telemetry: bool = False,
+                           slot_log: bool = False,
                            ) -> List[OnlineStats]:
     """Run a list of :class:`repro.online.sim.ClusterSim` scenarios as
     ONE batched dispatch; returns per-lane :class:`OnlineStats` in input
@@ -184,7 +187,10 @@ def run_device_sim_batched(sims: Sequence, n_quanta: int,
     whole-grid median wall over ``L * n_quanta`` (per-scenario cost).
     ``app_telemetry`` (implies ``telemetry``) attaches each lane's
     per-application ring as ``OnlineStats.app_telemetry`` — per-lane
-    rings are bit-identical to the single-dispatch twin's.
+    rings are bit-identical to the single-dispatch twin's.  ``slot_log``
+    attaches each lane's ``(Q, 3, C)`` occupant job ids, phase indices
+    and partner contexts as ``OnlineStats.slot_log``
+    (``device_sim._make_open_ops``).
 
     Spans: ``batch_sim.run`` around the call, and as its children, one
     after another, ``batch_sim.presample`` (lane checks and arrival
@@ -195,11 +201,12 @@ def run_device_sim_batched(sims: Sequence, n_quanta: int,
     """
     with obs_trace.span("batch_sim.run", lanes=len(sims), quanta=n_quanta):
         return _run_batched(sims, n_quanta, repeats, transfer_guard, warmup,
-                            telemetry or app_telemetry, app_telemetry)
+                            telemetry or app_telemetry, app_telemetry,
+                            slot_log)
 
 
 def _run_batched(sims, n_quanta, repeats, transfer_guard, warmup,
-                 telemetry, app_telemetry) -> List[OnlineStats]:
+                 telemetry, app_telemetry, slot_log) -> List[OnlineStats]:
     with obs_trace.span("batch_sim.presample", lanes=len(sims),
                         quanta=n_quanta):
         assert len(sims) >= 1, "batched run needs at least one scenario lane"
@@ -291,7 +298,7 @@ def _run_batched(sims, n_quanta, repeats, transfer_guard, warmup,
             fup = fspeed = max_retries = backoff = preserve = None
 
         key = _batch_key(spec, c, n_quanta, j_pad, telemetry, faulted,
-                         app_telemetry=app_telemetry)
+                         app_telemetry=app_telemetry, slot_log=slot_log)
         ent = _BATCH_CACHE.get(key)
         if ent is None:
             with obs_trace.span("batch_sim.compile_build", capacity=c,
@@ -299,7 +306,7 @@ def _run_batched(sims, n_quanta, repeats, transfer_guard, warmup,
                                 app_telemetry=app_telemetry):
                 ent = (spec.method, spec.model, _build_batched_race(
                     spec, params, c, n_quanta, j_pad, telemetry, faulted,
-                    app_telemetry=app_telemetry,
+                    app_telemetry=app_telemetry, slot_log=slot_log,
                 ))
             _BATCH_CACHE[key] = ent
             while len(_BATCH_CACHE) > _BATCH_CACHE_MAX:
@@ -344,12 +351,15 @@ def _run_batched(sims, n_quanta, repeats, transfer_guard, warmup,
         if faulted:
             retries, retry_at, evictions, requeues = fetched[fi:fi + 4]
             fi += 4
-        tlm = app_tlm = None
+        tlm = app_tlm = slots = None
         if telemetry:
             tlm = fetched[fi]
             fi += 1
         if app_telemetry:
             app_tlm = fetched[fi]
+            fi += 1
+        if slot_log:
+            slots = fetched[fi]
 
     stats_out: List[OnlineStats] = []
     with obs_trace.span("batch_sim.stats", lanes=L):
@@ -401,5 +411,7 @@ def _run_batched(sims, n_quanta, repeats, transfer_guard, warmup,
             if app_telemetry:
                 stats.app_telemetry = AppTelemetryLog(
                     APP_FIELDS, app_tlm[i], policy=name)
+            if slot_log:
+                stats.slot_log = slots[i]
             stats_out.append(stats)
     return stats_out

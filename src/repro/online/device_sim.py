@@ -172,7 +172,7 @@ class _LaneCfg(NamedTuple):
 def _make_open_ops(spec: ScanPolicy, params, capacity: int, j_pad: int,
                    admission: str, telemetry: bool = False,
                    faults_cfg=None, segment: bool = False,
-                   app_telemetry: bool = False):
+                   app_telemetry: bool = False, slot_log: bool = False):
     """Build the per-quantum scan ``body`` (plus ``carry0``/``unpack``)
     shared by the single-lane race (:func:`_build_race`) and the batched
     race (:func:`repro.online.batch_sim._build_batched_race`).
@@ -194,10 +194,19 @@ def _make_open_ops(spec: ScanPolicy, params, capacity: int, j_pad: int,
     vs ground-truth slowdown, signed residual, and the policy's ST
     stack estimates.  Identity/ground-truth columns come out of the
     ``open_slow_stats`` barrier shadow; the prediction column reuses
-    the scalar ring's ``cost`` gather — no new doctrine surface."""
+    the scalar ring's ``cost`` gather — no new doctrine surface.
+
+    ``slot_log`` (static, one-dispatch variant only) appends one more
+    output, ``(Q, 3, C)`` int32: per quantum and context the occupant's
+    job id (-1 when empty), its phase index as the quantum runs it and
+    its machine partner's context (itself when alone) — what a placement
+    replay needs to know job by job.  All three are integer values the
+    quantum already holds, so the log adds no consumer to its float
+    graph."""
     assert telemetry or not app_telemetry, (
         "app_telemetry implies telemetry in the open-system ops"
     )
+    assert not (segment and slot_log), "the slot log is one-dispatch only"
     lane = admission == "lane"
     lane_faults = faults_cfg == "lane"
     faults = faults_cfg is not None
@@ -261,7 +270,9 @@ def _make_open_ops(spec: ScanPolicy, params, capacity: int, j_pad: int,
         the max over the synergy lanes only instead of the whole grid.
         The returned ``head`` advance keeps the ungated ``n_admit`` —
         it is the value the live lanes select — and gated lanes return
-        their inputs untouched, exactly what the select replaces."""
+        their inputs untouched, exactly what the select replaces.  The
+        last return value is the lane's trip count (the ``syn_trips``
+        counter); the batch runs the maximum over its lanes."""
         n_admit = jnp.minimum(tail - head, jnp.sum(app_id < 0))
         n_trip = n_admit if trip_gate is None else jnp.where(
             trip_gate, n_admit, 0
@@ -292,7 +303,7 @@ def _make_open_ops(spec: ScanPolicy, params, capacity: int, j_pad: int,
             lambda s: s[0] < n_trip, body,
             (jnp.zeros((), jnp.int32), app_id, job_at),
         )
-        return app_id2, job_at2, job_at2 != job_at, head + n_admit
+        return app_id2, job_at2, job_at2 != job_at, head + n_admit, n_trip
 
     # ------------------------------------------------------------ policies
     def adjacent_partner(active, n_active):
@@ -465,19 +476,23 @@ def _make_open_ops(spec: ScanPolicy, params, capacity: int, j_pad: int,
                 free = free & ~rtake
             else:
                 free = app_id < 0
+            pre_app = app_id
+            syn_trips = jnp.int32(0)
             if admission == "synergy":
-                app_id, job_at, took_f, head = admit_synergy(
-                    app_id, job_at, carry.head, tail, job_pool,
-                    syn_cost, syn_mean,
-                )
+                with jax.named_scope("synergy"):
+                    app_id, job_at, took_f, head, syn_trips = admit_synergy(
+                        app_id, job_at, carry.head, tail, job_pool,
+                        syn_cost, syn_mean,
+                    )
             elif lane:
                 # Both rules run every quantum; the per-lane flag selects.
                 # The un-selected rule's outputs are dead values, so fifo
                 # lanes are value-independent of the (shared) synergy tables.
-                s_app, s_job, s_took, s_head = admit_synergy(
-                    app_id, job_at, carry.head, tail, job_pool,
-                    syn_cost, syn_mean, trip_gate=lane_cfg.is_syn,
-                )
+                with jax.named_scope("synergy"):
+                    s_app, s_job, s_took, s_head, syn_trips = admit_synergy(
+                        app_id, job_at, carry.head, tail, job_pool,
+                        syn_cost, syn_mean, trip_gate=lane_cfg.is_syn,
+                    )
                 f_app, f_job, f_took, f_head = admit_fifo(
                     app_id, job_at, free, carry.head, tail, job_pool,
                 )
@@ -561,7 +576,13 @@ def _make_open_ops(spec: ScanPolicy, params, capacity: int, j_pad: int,
                     fresh = jnp.where(lane_cfg.is_syn, False, took)
                 else:
                     fresh = jnp.zeros(c, bool) if use_hints else took
-                masks = jnp.stack([solve, solo_m, active, fresh])
+                rows = [solve, solo_m, active, fresh]
+                if use_hints:
+                    # The hinted rows keep their seeded stack: a newcomer's
+                    # slot may still hold its departed occupant's counters,
+                    # whose pair solve would overwrite the hint.
+                    rows.append(hint_m)
+                masks = jnp.stack(rows)
                 if telemetry:
                     cost, st, fdiag = fstep(carry.counters, carry.partner_prev,
                                             st, masks, odd)
@@ -631,6 +652,7 @@ def _make_open_ops(spec: ScanPolicy, params, capacity: int, j_pad: int,
             slow_mean, slow_max = open_slow_stats(
                 dt, app_id, active, phase_idx, partner
             )
+        run_phase = phase_idx
         counters, after, done, frac, phase_idx, phase_left = open_quantum(
             dt, app_id, active, phase_idx, phase_left, progress, target,
             partner, mkey, q, speed=speedq if faults else None,
@@ -681,6 +703,17 @@ def _make_open_ops(spec: ScanPolicy, params, capacity: int, j_pad: int,
         with jax.named_scope("telemetry"):
             if telemetry:
                 f32 = lambda v: v.astype(jnp.float32)  # noqa: E731
+                # Fresh placements beside a resident: the core-mate held a
+                # job before admission, or took one earlier in the queue
+                # order (a lower job id) this quantum.  Integers only; the
+                # core-mate view is a static swap of each core's two
+                # contexts, not a gather (which runs near-serially on a
+                # TPU).
+                def mate(x):
+                    return x.reshape(c // 2, 2)[:, ::-1].reshape(c)
+
+                beside = took_f & ((mate(pre_app) >= 0) | (
+                    mate(took_f) & (mate(job_at) < job_at)))
                 # ``done`` is derived from a float comparison, and *any*
                 # in-graph consumer of it (a sum, even a barrier) hands the
                 # quantum's float subgraph a different fusion and costs the
@@ -700,6 +733,7 @@ def _make_open_ops(spec: ScanPolicy, params, capacity: int, j_pad: int,
                     ]),
                     pol_diag,
                     jnp.zeros(5, jnp.float32),
+                    jnp.stack([f32(syn_trips), f32(jnp.sum(beside))]),
                 ])
                 outs = outs + (tvec,)
             if app_telemetry:
@@ -729,6 +763,8 @@ def _make_open_ops(spec: ScanPolicy, params, capacity: int, j_pad: int,
                     st4,
                 ], axis=1)
                 outs = outs + (avec,)
+        if slot_log:
+            outs = outs + (jnp.stack([job_at, run_phase, partner]),)
         return (new, fc_new), outs
 
     def carry0():
@@ -781,6 +817,9 @@ def _make_open_ops(spec: ScanPolicy, params, capacity: int, j_pad: int,
             res = res + (ys[k],)
             k += 1
         if app_telemetry:
+            res = res + (ys[k],)
+            k += 1
+        if slot_log:
             res = res + (ys[k],)
         return res
 

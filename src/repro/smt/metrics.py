@@ -160,6 +160,10 @@ class OnlineStats:
     #: Per-application ring (``repro.obs.telemetry.AppTelemetryLog``)
     #: when launched with ``app_telemetry=True``; None otherwise.
     app_telemetry: Optional[object] = None
+    #: (Q, 3, C) occupant job id (-1 = empty), phase index and partner
+    #: context per context when a batched device run was launched with
+    #: ``slot_log=True``.
+    slot_log: Optional[np.ndarray] = None
     #: Fault/resilience timelines + scalars (``repro.online.faults``); all
     #: None / 0 when the run had no FaultProfile.  failures/recoveries/
     #: straggling are fault-schedule data (identical on both engines by

@@ -339,12 +339,14 @@ class TestTelemetryLog:
         # schema change and must bump OBS_SCHEMA_VERSION
         assert CLOSED_FIELDS.index("real_slowdown_mean") == 0
         assert len(CLOSED_FIELDS) == 8
-        assert len(OPEN_FIELDS) == 21
+        assert len(OPEN_FIELDS) == 23
         assert set(CLOSED_FIELDS) < set(OPEN_FIELDS)
-        # the five fault counters ride at the tail (PR 8 extension)
-        assert OPEN_FIELDS[-5:] == (
+        # the five fault counters follow the policy fields, and the two
+        # synergy admission counters ride at the tail (appended after them)
+        assert OPEN_FIELDS[-7:-2] == (
             "failures", "recoveries", "evictions", "requeues", "straggling"
         )
+        assert OPEN_FIELDS[-2:] == ("syn_trips", "syn_beside")
 
 
 # -------------------------------------------------------- metrics registry

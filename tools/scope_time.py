@@ -9,7 +9,8 @@ less that of the ops nested in it on the same line) by the innermost of
 the program's ``jax.named_scope`` names in the op's ``op_name``
 metadata: ``machine``, ``synpa_step``, ``isc``, ``inverse``,
 ``pair_cost``, ``matcher`` (``seed``, ``two_opt``, ``repair``),
-``admission``, ``telemetry``; an op with none of them is ``unscoped``,
+``admission`` (``synergy``), ``telemetry``; an op with none of them is
+``unscoped``,
 and an op of a program the run did not compile is ``undumped``.
 A TPU op event carries no ``op_name``, so the metadata comes from the
 optimized HLO that the run dumps (``--xla_dump_to``; the persistent
@@ -32,8 +33,11 @@ import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SCOPES = ("machine", "synpa_step", "isc", "inverse", "pair_cost",
-          "matcher", "seed", "two_opt", "repair", "admission", "telemetry")
-SUB = {"seed", "two_opt", "repair"}
+          "matcher", "seed", "two_opt", "repair", "admission", "synergy",
+          "telemetry")
+#: Sub-scopes, reported under their parent's name.
+SUB = {"seed": "matcher", "two_opt": "matcher", "repair": "matcher",
+       "synergy": "admission"}
 OP_NAME = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.-]+) = .*op_name="([^"]*)"')
 
 
@@ -41,7 +45,7 @@ def scope_of(op_name: str) -> str:
     """The innermost known scope of an ``op_name`` path."""
     for part in reversed(op_name.split("/")):
         if part in SCOPES:
-            return f"matcher/{part}" if part in SUB else part
+            return f"{SUB[part]}/{part}" if part in SUB else part
     return "unscoped"
 
 
