@@ -24,9 +24,26 @@ What varies per lane and what is shared:
 * **Per lane (``in_axes=0``)** — the pre-sampled job arrays
   (arrival quantum / pool id / target, re-padded to the max ``j_pad``
   across lanes; padding jobs carry ``arrive_q == n_quanta`` so a wider
-  pad never changes a trajectory), the threefry key, the admission flag,
-  and — when any lane is faulted — the expanded fault schedule and the
-  retry knobs.
+  pad never changes a trajectory), the scenario seed, the admission
+  flag, and — when any lane is faulted — the expanded fault schedule and
+  the retry knobs.
+
+**What one dispatch moves.**  The shared tables stay **resident**: the
+profiled ``DeviceTables`` and the three synergy arrays are uploaded once
+per ``PhaseTables`` instance (and ``SynergyAdmission``, or the FIFO
+lanes' zero stand-ins), kept in ``_BATCH_CACHE`` beside the compiled
+races and freed by ``_BATCH_CACHE.clear()``; the ``scan.tables`` span
+appears only when they are uploaded.  The per-lane inputs go up as **one
+packed buffer per dtype**: an ``int32`` row per lane of job pool ids,
+arrival quanta, the seed (``seed mod 2**32`` as its ``uint32`` bits) and
+the admission flag, and a ``float32`` row of job targets; the race
+slices them apart outside the ``lax.scan`` and builds each lane's
+threefry key in-graph (``_lane_key``), bit-equal to
+``jax.random.PRNGKey(seed)``.  Faulted lanes' schedules and knobs go up
+as arrays of their own.  The outputs come back in **one**
+``jax.device_get``.  ``batch_sim.commit`` reports the host-to-device
+transfers it made as its ``uploads`` argument: 2 with the tables
+resident and no faulted lane.
 
 **Divergent control flow is masked data.**  The single-lane race picks
 its admission rule and fault constants at trace time (Python branches —
@@ -64,6 +81,7 @@ near-linear lane throughput is the accelerator story).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import time
 from collections import OrderedDict
@@ -93,19 +111,50 @@ from repro.smt.metrics import OnlineStats
 from repro.smt.scan_engine import DeviceTables, ScanPolicy
 
 
+def _lane_key(word):
+    """A lane's threefry key from its seed word (``int32`` bits of
+    ``seed mod 2**32``): ``jax.random.PRNGKey`` of the ``uint32`` word,
+    which is ``[0, seed mod 2**32]`` — bit-equal to the eager
+    ``jax.random.PRNGKey(seed)`` of every seed :func:`_seed_words`
+    accepts."""
+    return jax.random.PRNGKey(lax.bitcast_convert_type(word, jnp.uint32))
+
+
+def _seed_words(seeds: Sequence[int]) -> np.ndarray:
+    """The lanes' seeds as ``int32`` words for :func:`_lane_key`.
+
+    Without x64 the eager ``jax.random.PRNGKey(seed)`` narrows any seed
+    in int64 range to its low 32 bits, so the key is
+    ``[0, seed mod 2**32]``; with x64 it keeps the high word too, and the
+    two agree only on ``0 <= seed < 2**32``.  A seed outside the agreeing
+    range is refused rather than keyed differently."""
+    lo, hi = (0, 2**32) if jax.config.jax_enable_x64 else (-2**63, 2**63)
+    words = []
+    for s in seeds:
+        s = int(s)
+        if not lo <= s < hi:
+            raise ValueError(
+                f"scenario seed {s} is outside [{lo}, {hi}): its in-graph "
+                "key would differ from jax.random.PRNGKey(seed)")
+        words.append(s % 2**32)
+    return np.array(words, np.uint32).view(np.int32)
+
+
 def _build_batched_race(spec: ScanPolicy, params, capacity: int,
                         n_quanta: int, j_pad: int, telemetry: bool,
                         faulted: bool, app_telemetry: bool = False,
                         slot_log: bool = False):
     """One jitted, lane-batched open-system race.
 
-    ``race(dt, syn_cost, syn_mean, syn_stacks, job_pool (L, J),
-    job_arrive (L, J), job_target (L, J), mkey (L, 2), is_syn (L,),
-    fup, fspeed, max_retries, backoff, preserve)`` -> per-lane outputs,
-    every array of the single-lane race with a leading lane axis.  Lane
-    count is a trace-time shape: the same Python callable recompiles per
-    distinct L, and per-lane trajectories are L-invariant (vmap batches
-    every op elementwise over lanes).
+    ``race(dt, syn_cost, syn_mean, syn_stacks, ints (L, 2J + 2) i32,
+    job_target (L, J) f32, fup, fspeed, max_retries, backoff, preserve)``
+    -> per-lane outputs, every array of the single-lane race with a
+    leading lane axis.  A lane's ``ints`` row packs its job pool ids,
+    arrival quanta, seed word and admission flag (``_pack_ints``); the
+    race slices it apart outside the ``lax.scan``.  Lane count is a
+    trace-time shape: the same Python callable recompiles per distinct L,
+    and per-lane trajectories are L-invariant (vmap batches every op
+    elementwise over lanes).
     """
     body, carry0, unpack = _make_open_ops(
         spec, params, capacity, j_pad, "lane", telemetry,
@@ -113,10 +162,13 @@ def _build_batched_race(spec: ScanPolicy, params, capacity: int,
         slot_log=slot_log,
     )
 
-    def lane_race(dt, syn_cost, syn_mean, syn_stacks, job_pool,
-                  job_arrive, job_target, mkey, is_syn, fup, fspeed,
-                  max_retries, backoff, preserve):
-        lane_cfg = _LaneCfg(is_syn, max_retries, backoff, preserve)
+    def lane_race(dt, syn_cost, syn_mean, syn_stacks, ints, job_target,
+                  fup, fspeed, max_retries, backoff, preserve):
+        job_pool = ints[:j_pad]
+        job_arrive = ints[j_pad:2 * j_pad]
+        mkey = _lane_key(ints[2 * j_pad])
+        lane_cfg = _LaneCfg(ints[2 * j_pad + 1] != 0, max_retries, backoff,
+                            preserve)
         fn = functools.partial(body, dt, job_pool, job_arrive, job_target,
                                syn_cost, syn_mean, syn_stacks, mkey,
                                fup, fspeed, lane_cfg)
@@ -128,17 +180,43 @@ def _build_batched_race(spec: ScanPolicy, params, capacity: int,
     fax = 0 if faulted else None
     batched = jax.vmap(
         lane_race,
-        in_axes=(None, None, None, None, 0, 0, 0, 0, 0,
-                 fax, fax, fax, fax, fax),
+        in_axes=(None, None, None, None, 0, 0, fax, fax, fax, fax, fax),
     )
     return jax.jit(batched)
 
 
+def _pack_ints(job_pool: np.ndarray, job_arrive: np.ndarray,
+               seed_words: np.ndarray, is_syn: np.ndarray) -> np.ndarray:
+    """The lanes' integer inputs as one ``(L, 2J + 2)`` ``int32`` buffer:
+    ``[job_pool | job_arrive | seed word | is_syn]`` a row."""
+    return np.concatenate([job_pool, job_arrive, seed_words[:, None],
+                           is_syn.astype(np.int32)[:, None]], axis=1)
+
+
 # Compiled batched races keyed by their static configuration (the lane
-# count is a shape, handled by jit itself).  Same identity-keyed
-# method/model discipline as device_sim._RACE_CACHE.
+# count is a shape, handled by jit itself), and the resident tables keyed
+# ``("tables", id(PhaseTables), id(SynergyAdmission or None))``.  Same
+# identity-keyed discipline as device_sim._RACE_CACHE: every entry keeps
+# the objects its key names by id, so an id is never reused while cached.
 _BATCH_CACHE: "OrderedDict[Tuple, Tuple]" = OrderedDict()
 _BATCH_CACHE_MAX = 8
+
+#: Host-to-device transfers of one upload of the resident tables: the
+#: ``DeviceTables`` arrays (``n_apps`` is static) and the synergy three.
+_TABLE_UPLOADS = len(dataclasses.fields(DeviceTables)) - 1 + 3
+
+
+def _cache_get(key: Tuple):
+    ent = _BATCH_CACHE.get(key)
+    if ent is not None:
+        _BATCH_CACHE.move_to_end(key)
+    return ent
+
+
+def _cache_put(key: Tuple, ent: Tuple) -> None:
+    _BATCH_CACHE[key] = ent
+    while len(_BATCH_CACHE) > _BATCH_CACHE_MAX:
+        _BATCH_CACHE.popitem(last=False)
 
 
 def _batch_key(spec: ScanPolicy, capacity: int, n_quanta: int, j_pad: int,
@@ -194,10 +272,12 @@ def run_device_sim_batched(sims: Sequence, n_quanta: int,
 
     Spans: ``batch_sim.run`` around the call, and as its children, one
     after another, ``batch_sim.presample`` (lane checks and arrival
-    pre-sampling), ``.pack`` (the lanes' inputs stacked, the race looked
-    up or built), ``.commit`` (the host-to-device transfers),
-    ``.compile`` (the warm-up), ``.dispatch`` (each run, to
-    ``block_until_ready``), ``.fetch`` and ``.stats`` (the job records).
+    pre-sampling), ``.pack`` (the lanes' inputs packed, the race and the
+    resident tables looked up or the race built), ``.commit`` (the
+    host-to-device transfers, counted in its ``uploads`` argument; the
+    tables' upload, ``scan.tables``, only on a miss), ``.compile`` (the
+    warm-up), ``.dispatch`` (each run, to ``block_until_ready``),
+    ``.fetch`` (one ``device_get``) and ``.stats`` (the job records).
     """
     with obs_trace.span("batch_sim.run", lanes=len(sims), quanta=n_quanta):
         return _run_batched(sims, n_quanta, repeats, transfer_guard, warmup,
@@ -240,35 +320,26 @@ def _run_batched(sims, n_quanta, repeats, transfer_guard, warmup,
         # Synergy tables ship once; fifo lanes' selected path never reads
         # them, so sharing is value-neutral — but synergy lanes must agree.
         syn_lanes = [i for i, s in enumerate(sims) if s.admission == "synergy"]
-        if syn_lanes:
-            p0 = preps[syn_lanes[0]]
-            syn_cost, syn_mean = p0["syn_cost"], p0["syn_mean"]
-            syn_stacks = p0["syn_stacks"]
-            for i in syn_lanes[1:]:
-                assert (
-                    np.array_equal(preps[i]["syn_cost"], syn_cost)
-                    and np.array_equal(preps[i]["syn_mean"], syn_mean)
-                    and np.array_equal(preps[i]["syn_stacks"], syn_stacks)
-                ), "synergy lanes must share admission tables"
-        else:
-            syn_cost = preps[0]["syn_cost"]
-            syn_mean = preps[0]["syn_mean"]
-            syn_stacks = preps[0]["syn_stacks"]
+        syn_src = sims[syn_lanes[0]].synergy if syn_lanes else None
+        p0 = preps[syn_lanes[0] if syn_lanes else 0]
+        syn_cost, syn_mean = p0["syn_cost"], p0["syn_mean"]
+        syn_stacks = p0["syn_stacks"]
+        for i in syn_lanes[1:]:
+            assert (
+                np.array_equal(preps[i]["syn_cost"], syn_cost)
+                and np.array_equal(preps[i]["syn_mean"], syn_mean)
+                and np.array_equal(preps[i]["syn_stacks"], syn_stacks)
+            ), "synergy lanes must share admission tables"
 
-        job_pool = np.stack(
-            [_repad(p["job_pool"], j_pad, 0) for p in preps]
-        )
-        job_arrive = np.stack(
-            [_repad(p["job_arrive"], j_pad, n_quanta) for p in preps]
+        ints = _pack_ints(
+            np.stack([_repad(p["job_pool"], j_pad, 0) for p in preps]),
+            np.stack([_repad(p["job_arrive"], j_pad, n_quanta)
+                      for p in preps]),
+            _seed_words([s.seed for s in sims]),
+            np.array([s.admission == "synergy" for s in sims]),
         )
         job_target = np.stack(
             [_repad(p["job_target"], j_pad, np.inf) for p in preps]
-        )
-        mkeys = np.stack(
-            [np.asarray(jax.random.PRNGKey(s.seed)) for s in sims]
-        )
-        is_syn = np.array(
-            [s.admission == "synergy" for s in sims], dtype=bool
         )
         if faulted:
             # Unfaulted lanes ride an all-up unit-speed schedule: eviction
@@ -294,12 +365,13 @@ def _run_batched(sims, n_quanta, repeats, transfer_guard, warmup,
                 bool(p["fcfg"][2]) if f else True
                 for p, f in zip(preps, faulted_lane)
             ], bool)
+            fault_args = (fup, fspeed, max_retries, backoff, preserve)
         else:
-            fup = fspeed = max_retries = backoff = preserve = None
+            fault_args = ()
 
         key = _batch_key(spec, c, n_quanta, j_pad, telemetry, faulted,
                          app_telemetry=app_telemetry, slot_log=slot_log)
-        ent = _BATCH_CACHE.get(key)
+        ent = _cache_get(key)
         if ent is None:
             with obs_trace.span("batch_sim.compile_build", capacity=c,
                                 quanta=n_quanta, lanes=L,
@@ -308,26 +380,21 @@ def _run_batched(sims, n_quanta, repeats, transfer_guard, warmup,
                     spec, params, c, n_quanta, j_pad, telemetry, faulted,
                     app_telemetry=app_telemetry, slot_log=slot_log,
                 ))
-            _BATCH_CACHE[key] = ent
-            while len(_BATCH_CACHE) > _BATCH_CACHE_MAX:
-                _BATCH_CACHE.popitem(last=False)
-        else:
-            _BATCH_CACHE.move_to_end(key)
+            _cache_put(key, ent)
         race = ent[2]
+        tkey = ("tables", id(base.tables), id(syn_src))
+        resident = _cache_get(tkey)
 
-    with obs_trace.span("batch_sim.commit", lanes=L):
-        dev = lambda a: jax.device_put(jnp.asarray(a))  # noqa: E731
-        args = (
-            jax.device_put(DeviceTables.build(base.tables)),
-            dev(syn_cost), dev(syn_mean), dev(syn_stacks),
-            dev(job_pool), dev(job_arrive), dev(job_target),
-            dev(mkeys), dev(is_syn),
-        )
-        if faulted:
-            args = args + (dev(fup), dev(fspeed), dev(max_retries),
-                           dev(backoff), dev(preserve))
-        else:
-            args = args + (None, None, None, None, None)
+    uploads = 2 + len(fault_args) + (_TABLE_UPLOADS if resident is None
+                                     else 0)
+    with obs_trace.span("batch_sim.commit", lanes=L, uploads=uploads):
+        if resident is None:
+            resident = (base.tables, syn_src,
+                        DeviceTables.build(base.tables),
+                        *jax.device_put((syn_cost, syn_mean, syn_stacks)))
+            _cache_put(tkey, resident)
+        args = (resident[2:] + jax.device_put((ints, job_target))
+                + (jax.device_put(fault_args) if faulted else (None,) * 5))
     out = None
     if warmup:
         with obs_trace.span("batch_sim.compile", lanes=L):
@@ -344,7 +411,7 @@ def _run_batched(sims, n_quanta, repeats, transfer_guard, warmup,
         walls.append(time.perf_counter() - t0)
 
     with obs_trace.span("batch_sim.fetch", lanes=L):
-        fetched = tuple(np.asarray(o) for o in out)
+        fetched = jax.device_get(out)
         admit, finish, queue_depth, n_active, n_solo = fetched[:5]
         fi = 5
         retries = retry_at = evictions = requeues = None

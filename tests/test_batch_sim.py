@@ -28,6 +28,8 @@ from repro.online import (
     PoissonArrivals,
     SynergyAdmission,
 )
+from repro.obs import trace as obs_trace
+from repro.online import batch_sim
 from repro.online.batch_sim import run_device_sim_batched
 from repro.online.device_sim import run_device_sim
 from repro.smt import machine as mc
@@ -214,6 +216,131 @@ class TestBatchedOpenSystem:
             run_device_sim_batched(
                 [a, _sim(machine, pool, spec, other, seed=5)], QUANTA,
             )
+
+
+# ------------------------------------- one dispatch's host-device traffic
+EDGE_SEEDS = (0, 1, 2**31 - 1, 2**31, 2**32 - 1, 2**32 + 5, -1)
+
+
+def _lanes(machine, pool, spec, tables, synergy, mix):
+    """Two lanes at seeds 5 and 9: FIFO, synergy or one of each."""
+    admissions = {"fifo": ("fifo", "fifo"), "synergy": ("synergy",
+                  "synergy"), "mixed": ("fifo", "synergy")}[mix]
+    return [_sim(machine, pool, spec, tables, seed=s, rate=1.2,
+                 admission=a, synergy=synergy)
+            for s, a in zip((5, 9), admissions)]
+
+
+def _assert_stats_equal(a: OnlineStats, b: OnlineStats):
+    """Trajectories, admission and finish quanta, and every log the
+    dispatch hands back compare ``==``."""
+    _assert_lane_identical(a, b)
+    np.testing.assert_array_equal(a.admissions, b.admissions)
+    for log in ("telemetry", "app_telemetry"):
+        np.testing.assert_array_equal(getattr(a, log).data,
+                                      getattr(b, log).data)
+
+
+def _commit_uploads():
+    return [e["args"]["uploads"] for e in obs_trace.events()
+            if e["name"] == "batch_sim.commit"]
+
+
+@pytest.fixture
+def _spans_on():
+    obs_trace.clear()
+    obs_trace.enable()
+    yield
+    obs_trace.disable()
+    obs_trace.clear()
+
+
+class TestDispatchTraffic:
+    @pytest.mark.parametrize("seed", EDGE_SEEDS)
+    def test_in_graph_key_equals_eager_prngkey(self, seed):
+        """The lane key built inside the jit from the packed seed word is
+        bit-equal to the eager ``jax.random.PRNGKey(seed)``."""
+        keys = jax.jit(jax.vmap(batch_sim._lane_key))(
+            batch_sim._seed_words([seed, 7]))
+        np.testing.assert_array_equal(
+            np.asarray(keys[0]), np.asarray(jax.random.PRNGKey(seed)))
+
+    def test_seed_outside_agreeing_range_refused(self):
+        """A seed whose in-graph key could differ is refused, never
+        keyed differently: past int64, and with x64 outside
+        ``[0, 2**32)``."""
+        with pytest.raises(ValueError, match="outside"):
+            batch_sim._seed_words([2**64])
+        with jax.enable_x64(True):
+            for seed in (-1, 2**32 + 5):
+                with pytest.raises(ValueError, match="outside"):
+                    batch_sim._seed_words([seed])
+            words = batch_sim._seed_words([2**32 - 1])
+            keys = jax.jit(jax.vmap(batch_sim._lane_key))(words)
+            np.testing.assert_array_equal(
+                np.asarray(keys[0]),
+                np.asarray(jax.random.PRNGKey(2**32 - 1)))
+
+    @pytest.mark.parametrize("mix", ["fifo", "synergy", "mixed"])
+    def test_resident_call_bit_identical(self, machine, pool, spec, tables,
+                                         synergy, mix):
+        """A second call on resident tables repeats the first bit for bit
+        (admissions, finish quanta, both rings and the slot log), and
+        each lane still equals its single-dispatch twin."""
+        sims = _lanes(machine, pool, spec, tables, synergy, mix)
+        kw = dict(warmup=False, app_telemetry=True, slot_log=True)
+        first = run_device_sim_batched(sims, QUANTA, **kw)
+        second = run_device_sim_batched(sims, QUANTA, **kw)
+        for a, b, sim in zip(first, second, sims):
+            _assert_stats_equal(a, b)
+            np.testing.assert_array_equal(a.slot_log, b.slot_log)
+            _assert_stats_equal(
+                b, run_device_sim(sim, QUANTA, app_telemetry=True))
+
+    def test_resident_call_moves_two_uploads_one_fetch(
+        self, machine, pool, spec, tables, synergy, monkeypatch, _spans_on
+    ):
+        """With the tables resident a dispatch makes two host-to-device
+        transfers (the packed ``int32`` and ``float32`` buffers) and one
+        fetch, and uploads no tables."""
+        sims = _lanes(machine, pool, spec, tables, synergy, "mixed")
+        run_device_sim_batched(sims, QUANTA, warmup=False)
+        puts, gets = [], []
+        put, get = jax.device_put, jax.device_get
+
+        def counting_put(x, *a, **kw):
+            puts.append(len(jax.tree_util.tree_leaves(x)))
+            return put(x, *a, **kw)
+
+        def counting_get(x):
+            gets.append(x)
+            return get(x)
+
+        monkeypatch.setattr(jax, "device_put", counting_put)
+        monkeypatch.setattr(jax, "device_get", counting_get)
+        obs_trace.clear()
+        run_device_sim_batched(sims, QUANTA, warmup=False)
+        assert sum(puts) == 2 and len(gets) == 1
+        assert _commit_uploads() == [2]
+        assert "scan.tables" not in obs_trace.record()
+
+    def test_tables_uploaded_afresh_after_clear_or_new_tables(
+        self, machine, pool, spec, tables, _spans_on
+    ):
+        """``_BATCH_CACHE.clear()`` frees the resident tables, and another
+        ``PhaseTables`` instance gets its own upload."""
+        sims = [_sim(machine, pool, spec, tables, seed=s) for s in (3, 4)]
+        run_device_sim_batched(sims, QUANTA, warmup=False)
+        run_device_sim_batched(sims, QUANTA, warmup=False)
+        batch_sim._BATCH_CACHE.clear()
+        run_device_sim_batched(sims, QUANTA, warmup=False)
+        other = PhaseTables.build(pool)
+        run_device_sim_batched(
+            [_sim(machine, pool, spec, other, seed=s) for s in (3, 4)],
+            QUANTA, warmup=False)
+        miss = 2 + batch_sim._TABLE_UPLOADS
+        assert _commit_uploads()[1:] == [2, miss, miss]
+        assert obs_trace.record()["scan.tables"][0].size >= 2
 
 
 # ------------------------------------------------- closed-race batching
